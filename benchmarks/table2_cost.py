@@ -23,6 +23,7 @@ import numpy as np
 from repro import optim
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy, losses
+from repro.core.sharding import make_mesh
 from repro.costmodel import flops as flopslib, pricing
 from repro.models import build_cnn
 from repro.serverless import (PAPER_TABLE2, ServerlessSetup,
@@ -37,7 +38,7 @@ def _measure_cnn_step(kind: str, batch=64) -> float:
     full width by the conv-FLOP ratio (width^2)."""
     cfg = get_config(kind).reduced()
     model = build_cnn(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def loss_fn(params, b):
         logits, _ = model.apply(params, b)

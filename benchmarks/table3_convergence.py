@@ -21,6 +21,7 @@ import numpy as np
 from repro import optim
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy, losses
+from repro.core.sharding import make_mesh
 from repro.data import cifar_like
 from repro.models import build_cnn
 from repro.serverless import ARCHS, get_arch, simulate_epoch
@@ -37,7 +38,7 @@ STRATS = {name: (get_arch(name).jax_strategy,
 def run(csv_rows, steps=50, batch=96):
     imgs, labels = cifar_like(4096, seed=0)
     test_imgs, test_labels = cifar_like(512, seed=99)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("mobilenet-cifar").reduced()
 
     def loss_fn(params, b):

@@ -11,6 +11,7 @@ import numpy as np
 from repro import optim
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy
+from repro.core.sharding import make_mesh
 from repro.data import lm_batches, token_stream
 from repro.models import build_model
 
@@ -18,7 +19,7 @@ from repro.models import build_model
 def main():
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     stream = token_stream(200_000, cfg.vocab_size)
     batches = lm_batches(stream, batch=16, seq=64)

@@ -15,6 +15,7 @@ import numpy as np
 from repro import optim
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy, losses
+from repro.core.sharding import make_mesh
 from repro.data import cifar_like
 from repro.models import build_cnn
 
@@ -28,7 +29,7 @@ def main():
     cfg = get_config("mobilenet-cifar").reduced()
     imgs, labels = cifar_like(8192, seed=0)
     test_imgs, test_labels = cifar_like(1024, seed=99)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     for sname, kw in (("spirt", {"microbatches": 4}),
                       ("mlless", {"threshold": 0.7})):

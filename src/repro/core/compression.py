@@ -15,11 +15,11 @@ reduction visible in the dry-run HLO (EXPERIMENTS.md §Perf).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.strategies import Strategy, _leaf_bytes
 
@@ -60,8 +60,7 @@ class QuantizedScatterReduce(Strategy):
             raise ValueError("QuantizedScatterReduce.sync needs at "
                              "least one mesh axis name")
         axis_names = axes if len(axes) > 1 else axes[0]
-        from repro.compat import axis_size as _axis_size
-        W = int(np.prod([_axis_size(a) for a in axes]))
+        W = math.prod(jax.lax.axis_size(a) for a in axes)
 
         new_resid, out = [], []
         for g, r in zip(jax.tree.leaves(grads), jax.tree.leaves(state)):

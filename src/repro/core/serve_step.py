@@ -32,7 +32,6 @@ def build_serve_step(model, mesh, *, data_axes=("data",),
                      model_axis="model", batch_size: int,
                      cache_len: int, swa_variant: bool = False):
     cfg = model.cfg
-    model.param_hook = None
     example_params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     pspecs = sharding.param_pspecs(example_params, mesh, fsdp=False,
                                    data_axes=data_axes,

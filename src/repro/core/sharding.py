@@ -16,7 +16,23 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None) -> Mesh:
+    """The one mesh builder: every axis ``AxisType.Auto``.
+
+    The train step runs its data axes manual inside ``jax.shard_map`` and
+    leaves ``model`` to GSPMD, which needs Auto axes (``jax.make_mesh``
+    defaults to Explicit).  ``devices`` lays the given devices out in
+    order; ``None`` lets ``jax.make_mesh`` pick them.
+    """
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types=types)
+    return Mesh(np.asarray(devices).reshape(tuple(shape)), tuple(axes),
+                axis_types=types)
 
 
 def _axis_size(mesh, axes) -> int:
@@ -127,7 +143,7 @@ def survivor_mesh(mesh, dead: int, *, data_axis: str = "data"):
             f"cannot remove the last {data_axis!r} shard (size {n}); "
             "a one-worker fleet has no survivors to re-mesh")
     keep = np.delete(devs, dead, axis=axis)
-    return jax.sharding.Mesh(keep, mesh.axis_names)
+    return make_mesh(keep.shape, mesh.axis_names, devices=keep)
 
 
 def shardings(tree_pspecs, mesh):

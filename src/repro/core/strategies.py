@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size as _axis_size
-
 
 def _leaf_bytes(tree) -> int:
     return sum(np.prod(l.shape) * jnp.dtype(l.dtype).itemsize
@@ -110,7 +108,7 @@ class ScatterReduce(Strategy):
 
     def sync(self, grads, state, axis_names):
         axes = (axis_names,) if isinstance(axis_names, str) else axis_names
-        W = np.prod([_axis_size(a) for a in axes])
+        W = np.prod([jax.lax.axis_size(a) for a in axes])
 
         def one(g):
             flat = g.reshape(-1).astype(jnp.float32)

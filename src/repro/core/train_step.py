@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import losses, sharding
 from repro.core.strategies import Strategy
 from repro.optim.optimizers import Optimizer, apply_updates
@@ -140,9 +139,8 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
                                          fsdp_rs_dtype)(g)
             return jax.tree.map(one, tree, specs,
                                 is_leaf=lambda x: isinstance(x, P))
-        model.param_hook = param_hook
     else:
-        model.param_hook = None
+        param_hook = None
 
     flat_specs, spec_treedef = jax.tree.flatten(
         pspecs, is_leaf=lambda x: isinstance(x, P))
@@ -254,7 +252,7 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
 
     def make_sm(batch_keys):
         bspec = {k: P(dp) for k in batch_keys}
-        return shard_map(
+        return jax.shard_map(
             step_body, mesh=mesh,
             in_specs=(state_manual, bspec),
             out_specs=(state_manual, metrics_manual),
@@ -262,7 +260,14 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
 
     @functools.partial(jax.jit, static_argnames=())
     def step_fn(state, batch):
-        return make_sm(tuple(sorted(batch)))(state, batch)
+        # one model may serve several builders (a survivor mesh, serving):
+        # install this step's FSDP hook only while this step traces
+        prev = getattr(model, "param_hook", None)
+        model.param_hook = param_hook
+        try:
+            return make_sm(tuple(sorted(batch)))(state, batch)
+        finally:
+            model.param_hook = prev
 
     # ---------------- full (auto+manual) shardings for placement -------
     full_pspecs = pspecs
@@ -282,7 +287,10 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
         params = jax.tree.map(
             lambda p, s: jax.device_put(p, NamedSharding(mesh, s)),
             params, pspecs, is_leaf=lambda x: isinstance(x, P))
-        opt_state = optimizer.init(params)
+        # counters go on the mesh too, where the step returns them, or
+        # the second step's new input types compile the step again
+        opt_state = jax.device_put(optimizer.init(params),
+                                   state_shardings["opt"])
         sync_like_r = [l for l, m in zip(jax.tree.leaves(params), fsdp_mask)
                        if not m]
         # worker-local strategy state: leading dim = dp world size,
@@ -293,7 +301,8 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
                 NamedSharding(mesh, P(dp_spec))),
             strategy.init_state(sync_like_r))
         return {"params": params, "opt": opt_state, "strat": strat_state,
-                "step": jnp.zeros((), jnp.int32)}
+                "step": jax.device_put(jnp.zeros((), jnp.int32),
+                                       state_shardings["step"])}
 
     batch_shardings = {k: NamedSharding(mesh, P(dp))
                        for k in ("tokens", "labels")}

@@ -17,6 +17,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import block_significance as _bs
 from repro.kernels import fused_adamw as _fa
@@ -34,7 +35,23 @@ def resolve_interpret(interpret) -> bool:  # repro: allow[kernel-ref-parity] -- 
     return default_interpret() if interpret is None else bool(interpret)
 
 
-INTERPRET = default_interpret()
+def _whole_on_auto_axes(fn, *args):
+    """Call ``fn`` on whole operands on every device of the ambient
+    mesh's Auto axes.
+
+    Mosaic kernels cannot be partitioned by GSPMD.  Inside the train
+    step's ``shard_map`` the data axes are manual and ``model`` is left
+    Auto, so a kernel there is wrapped in a ``shard_map`` over the Auto
+    axes with replicated operands; with no Auto axis in scope ``fn`` is
+    called as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto}
+    if not auto:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * len(args),
+                         out_specs=P(), axis_names=auto,
+                         check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -46,8 +63,7 @@ def _swa_core(q, k, v, window, causal):
     qb = 256 if S % 256 == 0 else (128 if S % 128 == 0 else S)
     kb = qb
     return _swa.swa_attention_fwd(q, k, v, window=window, causal=causal,
-                                  q_block=qb, kv_block=kb,
-                                  interpret=INTERPRET)
+                                  q_block=qb, kv_block=kb)
 
 
 def _swa_fwd(q, k, v, window, causal):
@@ -76,7 +92,7 @@ def swa_attention(q, k, v, *, window=None, causal=True):
 # ---------------------------------------------------------------------------
 def block_significance(blocks, threshold):
     """blocks: (n, b) -> bool mask of significant blocks."""
-    sq = _bs.block_norms(blocks, interpret=INTERPRET)
+    sq = _whole_on_auto_axes(_bs.block_norms, blocks)
     rms = jnp.sqrt(jnp.mean(sq) + 1e-20)
     return jnp.sqrt(sq) > threshold * rms
 
@@ -84,7 +100,7 @@ def block_significance(blocks, threshold):
 def significance_filter(blocks, threshold):
     """Returns (kept, residual, mask) in one fused pass."""
     mask = block_significance(blocks, threshold)
-    kept, resid = _bs.masked_filter(blocks, mask, interpret=INTERPRET)
+    kept, resid = _whole_on_auto_axes(_bs.masked_filter, blocks, mask)
     return kept, resid, mask
 
 
@@ -98,8 +114,7 @@ def wkv6(r, k, v, logw, u, *, chunk=64):
     c = chunk
     while T % c:
         c //= 2
-    return _w.wkv6_chunked(r, k, v, logw, u, chunk=max(c, 1),
-                           interpret=INTERPRET)
+    return _w.wkv6_chunked(r, k, v, logw, u, chunk=max(c, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -108,9 +123,10 @@ def wkv6(r, k, v, logw, u, *, chunk=64):
 def fused_adamw(g, m, v, p, *, lr, b1, b2, eps, wd, c1, c2):
     """Pytree-leaf update: any-shape operands, flattened internally."""
     shape = g.shape
-    out = _fa.fused_adamw_flat(
+    out = _whole_on_auto_axes(
+        functools.partial(_fa.fused_adamw_flat, lr=lr, b1=b1, b2=b2,
+                          eps=eps, wd=wd),
         g.reshape(-1), m.reshape(-1), v.reshape(-1), p.reshape(-1),
-        jnp.asarray(c1), jnp.asarray(c2), lr=lr, b1=b1, b2=b2, eps=eps,
-        wd=wd, interpret=INTERPRET)
+        jnp.asarray(c1), jnp.asarray(c2))
     u, m_new, v_new = (x.reshape(shape) for x in out)
     return u.astype(p.dtype), m_new, v_new

@@ -1,14 +1,16 @@
 """Sliding-window flash-attention forward — Pallas TPU kernel.
 
-Tiling: grid (batch, kv_head, q_blocks).  Each program holds one
-(Bq, hd) query tile in VMEM plus the full per-(b, kv-head) K/V strips
-(the window bounds how much is ever *read*: the kv loop runs only over
-blocks intersecting [q_start - window + 1, q_end], with a traced-bound
-``fori_loop`` so out-of-window blocks cost nothing).  Online softmax in
-fp32 accumulators, GQA folded into the tile's head-group dim.
+Tiling: grid (batch, kv_head, q_blocks).  Operands are laid out
+head-major — q as (B, KV, G, S, hd), k/v as (B, KV, S, hd) — so every
+block's last two dims are (rows, hd), as Mosaic requires.  Each program
+holds the G query heads of one KV head for one (Bq, hd) query tile in
+VMEM plus the full per-(b, kv-head) K/V strips (the window bounds how
+much is ever *read*: the kv loop runs only over blocks intersecting
+[q_start - window + 1, q_end], with a traced-bound ``fori_loop`` so
+out-of-window blocks cost nothing).  Online softmax in fp32
+accumulators over the (G * Bq) rows of the GQA group.
 
-MXU alignment: Bq and Ck are multiples of 128 where shapes allow;
-``ops.swa_attention`` pads the head_dim/seq to legal tiles.
+MXU alignment: Bq and Ck are multiples of 128 where shapes allow.
 """
 from __future__ import annotations
 
@@ -23,12 +25,12 @@ NEG_INF = -1e30
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, window, causal, q_block,
                  kv_block, seq_len):
-    # q_ref: (q_block, G, hd); k_ref/v_ref: (seq, hd); o_ref like q_ref
+    # q_ref/o_ref: (G, q_block, hd); k_ref/v_ref: (seq, hd)
     qi = pl.program_id(2)
     q_start = qi * q_block
-    q = q_ref[...].astype(jnp.float32)                 # (Bq, G, hd)
-    G = q.shape[1]
-    hd = q.shape[2]
+    G, _, hd = q_ref.shape
+    rows = G * q_block
+    q = q_ref[...].astype(jnp.float32).reshape(rows, hd)
     scale = 1.0 / (hd ** 0.5)
 
     n_kv = seq_len // kv_block
@@ -40,42 +42,41 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, window, causal, q_block,
     hi = jnp.minimum((q_start + q_block - 1) // kv_block + 1, n_kv) \
         if causal else n_kv
 
-    q_pos = q_start + jax.lax.iota(jnp.int32, q_block)
+    # row r of the (G * Bq) group holds query position q_start + r % Bq
+    q_pos = q_start + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, kv_block), 0) % q_block
+    kv_off = jax.lax.broadcasted_iota(jnp.int32, (rows, kv_block), 1)
 
     def body(ki, carry):
         m, l, acc = carry
-        k_start = ki * kv_block
+        k_start = pl.multiple_of(ki * kv_block, kv_block)
         k = k_ref[pl.ds(k_start, kv_block), :].astype(jnp.float32)
         v = v_ref[pl.ds(k_start, kv_block), :].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q.reshape(q_block * G, hd), k,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (Bq*G, Ck)
-        s = s.reshape(q_block, G, kv_block)
-        kv_pos = k_start + jax.lax.iota(jnp.int32, kv_block)
-        mask = jnp.ones((q_block, kv_block), jnp.bool_)
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (rows, Ck)
+        kv_pos = k_start + kv_off
+        mask = jnp.ones((rows, kv_block), jnp.bool_)
         if causal:
-            mask &= kv_pos[None, :] <= q_pos[:, None]
+            mask &= kv_pos <= q_pos
         if window is not None:
-            mask &= kv_pos[None, :] > q_pos[:, None] - window
-        s = jnp.where(mask[:, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
+            mask &= kv_pos > q_pos - window
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p, axis=-1)
+        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p.reshape(q_block * G, kv_block), v,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(q_block, G, hd)
-        acc_new = acc * corr[..., None] + pv
-        return m_new, l_new, acc_new
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (rows, hd)
+        return m_new, l_new, acc * corr + pv
 
-    m0 = jnp.full((q_block, G), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((q_block, G), jnp.float32)
-    a0 = jnp.zeros((q_block, G, hd), jnp.float32)
+    m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((rows, 1), jnp.float32)
+    a0 = jnp.zeros((rows, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(
-        o_ref.dtype)
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).reshape(
+        G, q_block, hd).astype(o_ref.dtype)
 
 
 def swa_attention_fwd(q, k, v, *, window=None, causal=True,
@@ -92,25 +93,25 @@ def swa_attention_fwd(q, k, v, *, window=None, causal=True,
     kv_block = min(kv_block, S)
     assert S % q_block == 0 and S % kv_block == 0, (S, q_block, kv_block)
 
-    # (B, S, KV, G, hd) so the grid can map (batch, kv_head, q_tile)
-    qr = q.reshape(B, S, KV, G, hd)
+    # head-major: query head kv * G + g -> (B, KV, G, S, hd)
+    qh = q.reshape(B, S, KV, G, hd).transpose(0, 2, 3, 1, 4)
+    kh = k.transpose(0, 2, 1, 3)
+    vh = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _attn_kernel, window=window, causal=causal, q_block=q_block,
         kv_block=kv_block, seq_len=S)
 
+    q_spec = pl.BlockSpec((None, None, G, q_block, hd),
+                          lambda b, h, qi: (b, h, 0, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, S, hd),
+                           lambda b, h, qi: (b, h, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid=(B, KV, S // q_block),
-        in_specs=[
-            pl.BlockSpec((None, q_block, None, G, hd),
-                         lambda b, h, qi: (b, qi, h, 0, 0)),
-            pl.BlockSpec((None, S, None, hd), lambda b, h, qi: (b, 0, h, 0)),
-            pl.BlockSpec((None, S, None, hd), lambda b, h, qi: (b, 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, q_block, None, G, hd),
-                               lambda b, h, qi: (b, qi, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, KV, G, hd), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, S, hd), q.dtype),
         interpret=interpret,
-    )(qr, k, v)
-    return out.reshape(B, S, KV * G, hd)
+    )(qh, kh, vh)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)

@@ -25,11 +25,17 @@ def src_root() -> str:
 
 
 def child_env(devices: int) -> Dict[str, str]:
-    """A copy of the environment forcing ``devices`` host devices and
-    putting this repo's ``src/`` first on the child's PYTHONPATH."""
+    """A copy of the environment for a virtual-device fleet simulation:
+    the CPU backend with ``devices`` host devices, and this repo's
+    ``src/`` first on the child's PYTHONPATH.
+
+    The child is pinned to the CPU even where an accelerator is
+    attached, so it never contends with its parent for the chip and
+    always sees the fleet it asked for, not the chip's device count."""
     if devices < 1:
         raise ValueError(f"devices must be >= 1, got {devices}")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}")
     env["PYTHONPATH"] = (src_root() + os.pathsep

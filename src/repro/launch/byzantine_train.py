@@ -62,6 +62,7 @@ def run(inner: str = "trimmed_mean", *, attack: str = "scale",
     from repro import optim
     from repro.configs.base import get_config
     from repro.core import build_train_step, get_strategy, losses
+    from repro.core.sharding import make_mesh
     from repro.data import cifar_like
     from repro.models import build_cnn
 
@@ -69,7 +70,7 @@ def run(inner: str = "trimmed_mean", *, attack: str = "scale",
     imgs, labels = cifar_like(data_size, seed=0)
     timgs, tlabels = cifar_like(eval_size, seed=99)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = make_mesh((n_dev, 1), ("data", "model"))
     bsh = NamedSharding(mesh, P("data"))
     model = build_cnn(cfg)
 

@@ -2,19 +2,14 @@
 touches jax device state)."""
 from __future__ import annotations
 
-import jax
+from repro.core.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 v5e chips) or 2x16x16 two-pod (512) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU tests (requires enough host devices)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes_of(mesh) -> tuple:

@@ -14,6 +14,8 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.core import build_serve_step
+from repro.core.sharding import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 
 
@@ -26,12 +28,13 @@ def main():
     ap.add_argument("--decode-tokens", type=int, default=16)
     ap.add_argument("--mesh", default="1x1")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     d, m = (int(x) for x in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     model = build_model(cfg)
     cache_len = args.prompt_len + args.decode_tokens
     ss = build_serve_step(model, mesh, batch_size=args.batch,

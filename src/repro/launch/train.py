@@ -22,7 +22,9 @@ from repro import optim
 from repro.checkpoint import restore, save
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy, losses
+from repro.core.sharding import make_mesh
 from repro.data import cifar_like, lm_batches, token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_cnn, build_model
 
 
@@ -44,17 +46,15 @@ def main():
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--fused-optimizer", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     dims = tuple(int(x) for x in args.mesh.split("x"))
-    if jax.default_backend() == "tpu":
-        from repro.launch.distributed import initialize_distributed
-        initialize_distributed()
     axes = ("data", "model") if len(dims) == 2 else \
         ("pod", "data", "model")
-    mesh = jax.make_mesh(dims, axes)
+    mesh = make_mesh(dims, axes)
 
     is_cnn = cfg.family == "cnn"
     if is_cnn:

@@ -16,15 +16,10 @@ def shard_hint(x, *axes):
     ``axes`` entries are mesh-axis names (or None) per tensor dim; axes
     not present in the current abstract mesh are dropped, so model code
     stays mesh-agnostic (no-op on CPU tests / 1x1 meshes)."""
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    names = getattr(mesh, "axis_names", ()) or ()
-    try:  # only Auto axes may appear in with_sharding_constraint specs
-        types = dict(zip(names, mesh.axis_types))
-        names = tuple(n for n in names
-                      if types[n] == jax.sharding.AxisType.Auto)
-    except AttributeError:
-        pass
+    mesh = jax.sharding.get_abstract_mesh()
+    # only Auto axes may appear in with_sharding_constraint specs
+    names = tuple(n for n, t in zip(mesh.axis_names, mesh.axis_types)
+                  if t == jax.sharding.AxisType.Auto)
     spec = tuple(a if (a in names) else None for a in axes)
     if not any(spec):
         return x
@@ -153,4 +148,7 @@ def unembed(p, x):
 
 
 def unembed_init(key, d_model, vocab, dtype):
-    return {"table": dense_init(key, (d_model, vocab), dtype)}
+    # std 0.02, the published llama-family initializer_range: initial
+    # logits ~N(0, 0.02^2 * d_model) stay near uniform, so the step-0
+    # loss sits near ln(vocab) rather than ln(vocab) + 1/2
+    return {"table": dense_init(key, (d_model, vocab), dtype, scale=0.02)}

@@ -116,8 +116,7 @@ def moe_apply_ep(p, x, cfg, *, axis_name, ep_degree=None):
     and returned.  Numerics match :func:`moe_apply` up to capacity-drop
     ordering (validated in tests/test_moe_ep.py).
     """
-    from repro.compat import axis_size as _axis_size
-    W = _axis_size(axis_name)
+    W = jax.lax.axis_size(axis_name)
     E, k = cfg.n_experts, cfg.experts_per_token
     assert E % W == 0, (E, W)
     E_loc = E // W

@@ -199,12 +199,10 @@ class ResilientTrainer:
         """(mesh, TrainStep) for a device tuple — FSDP-style: a pure
         data-parallel axis plus a width-1 'model' axis; param/optimizer
         leaves shard over 'data' where divisible (picodo idiom)."""
-        import jax
-
         from repro.core import build_train_step
-        mesh = jax.sharding.Mesh(
-            np.asarray(devices).reshape(len(devices), 1),
-            ("data", "model"))
+        from repro.core.sharding import make_mesh
+        mesh = make_mesh((len(devices), 1), ("data", "model"),
+                         devices=devices)
         ts = build_train_step(self.model, self.optimizer, self.strategy,
                               mesh, fsdp=self.config.fsdp)
         return mesh, ts

@@ -249,11 +249,10 @@ def _linear_axis_index(axis_names):
     """Flattened data-parallel worker index inside a shard_map body."""
     import jax
 
-    from repro.compat import axis_size
     axes = (axis_names,) if isinstance(axis_names, str) else tuple(axis_names)
     idx = jax.lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 

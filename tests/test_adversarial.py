@@ -411,17 +411,18 @@ def test_jax_gaussian_noise_is_fresh_per_step():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from repro.core.sharding import make_mesh
     spec = adv.get_attack("gaussian_noise")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     g = {"a": jnp.ones((1, 4), jnp.float32)}
     specs = jax.tree.map(lambda _: P("data"), g)
 
     def corrupt(step):
-        f = shard_map(
+        f = jax.shard_map(
             lambda x: spec.jax_apply(x, jnp.asarray(True), "data", 5.0,
                                      7, jnp.asarray(step))["a"],
-            mesh=mesh, in_specs=(specs,), out_specs=P("data"))
+            mesh=mesh, in_specs=(specs,), out_specs=P("data"),
+            check_vma=False)
         return np.asarray(f(g))
 
     s0, s0b, s1 = corrupt(0), corrupt(0), corrupt(1)
@@ -439,15 +440,11 @@ def test_jax_gaussian_noise_is_fresh_per_step():
 # Real-training regressions (subprocess: own XLA device count)
 # ---------------------------------------------------------------------------
 def _run_subprocess_code(code, timeout=560):
-    import os
     import subprocess
     import sys
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable, "-c", code], env=env,
+
+    from repro.launch._subprocess import child_env
+    out = subprocess.run([sys.executable, "-c", code], env=child_env(4),
                          capture_output=True, text=True, timeout=timeout)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout, out.stdout[-2000:]
@@ -465,11 +462,11 @@ def test_krum_and_geometric_median_sync_match_numpy_twins():
     _run_subprocess_code(textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.serverless.adversarial import (np_geometric_median,
                                                   np_krum)
+        from repro.core.sharding import make_mesh
         from repro.serverless.recovery import GeometricMedian, Krum
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         r = np.random.RandomState(0)
         grads = {"a": jnp.asarray(r.randn(4, 8, 3), jnp.float32),
                  "b": jnp.asarray(r.randn(4, 5), jnp.float32)}
@@ -484,8 +481,9 @@ def test_krum_and_geometric_median_sync_match_numpy_twins():
                 (GeometricMedian(tol=1e-7, max_iter=300),
                  lambda s: np_geometric_median(s, tol=1e-10,
                                                max_iter=600))):
-            f = shard_map(lambda g: strat.sync(g, (), "data")[0],
-                          mesh=mesh, in_specs=(specs,), out_specs=specs)
+            f = jax.shard_map(lambda g: strat.sync(g, (), "data")[0],
+                              mesh=mesh, in_specs=(specs,),
+                              out_specs=specs, check_vma=False)
             out = f(grads)
             want = ref(stack)
             got = np.concatenate([np.asarray(out[k][0]).ravel()

@@ -15,6 +15,7 @@ import pytest
 from repro import optim
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy
+from repro.core.sharding import make_mesh
 from repro.models import build_cnn, build_model
 
 ARCHS = [
@@ -55,7 +56,7 @@ def test_forward_shapes_no_nans(arch):
 @pytest.mark.parametrize("arch", ARCHS)
 def test_one_train_step(arch):
     cfg = get_config(arch).reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = build_model(cfg, remat=False)
     ts = build_train_step(model, optim.adamw(1e-3),
                           get_strategy("allreduce"), mesh)

@@ -10,6 +10,7 @@ from repro import optim
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy
 from repro.core.compression import QuantizedScatterReduce, _dequant, _quant
+from repro.core.sharding import make_mesh
 from repro.models import build_model
 
 
@@ -23,7 +24,7 @@ def test_quant_roundtrip_accuracy():
 def test_quantized_sync_close_to_allreduce():
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = np.random.RandomState(0)
     batch = {"tokens": r.randint(0, cfg.vocab_size, (8, 32)).astype(
         np.int32)}
@@ -74,17 +75,16 @@ def test_ef_residual_roundtrip_padded_tail():
     tail; the residual must be the error-feedback term of the ORIGINAL
     (unpadded) slice, reshaped to the gradient's shape."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     qsr = QuantizedScatterReduce(chunk=512)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jnp.asarray(np.random.RandomState(2).randn(2, 515), jnp.float32)
 
     def body(g):
         out, resid, info = qsr.sync([g], [jnp.zeros_like(g)], "data")
         return out[0], resid[0]
 
-    out, resid = shard_map(body, mesh=mesh, in_specs=P(),
-                           out_specs=P(), check_vma=False)(x)
+    out, resid = jax.shard_map(body, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False)(x)
     assert out.shape == x.shape and resid.shape == x.shape
     # the residual is exactly acc - dequant(quant(acc)) on the unpadded
     # slice (the padded tail quantizes but never feeds back)
@@ -136,7 +136,7 @@ def test_mlless_converges_with_compression():
     from repro.serverless.archs import get_arch
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = np.random.RandomState(0)
     batch = {"tokens": r.randint(0, cfg.vocab_size, (8, 32)).astype(
         np.int32)}
@@ -157,7 +157,7 @@ def test_quantized_converges_with_compression():
     from repro.serverless.archs import get_arch
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = np.random.RandomState(0)
     batch = {"tokens": r.randint(0, cfg.vocab_size, (8, 32)).astype(
         np.int32)}

@@ -8,6 +8,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core.flash_decode import flash_decode_attention
+from repro.core.sharding import make_mesh
 from repro.models.attention import decode_attention
 
 
@@ -17,7 +18,7 @@ def test_flash_decode_matches_reference(window, pos_past_wrap):
     if len(jax.devices()) < 2:
         pytest.skip("needs >=2 host devices")
     n_dev = min(4, len(jax.devices()))
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     B, L, KV, G, hd = 2, 64, 2, 3, 32
     H = KV * G
     rs = np.random.RandomState(0)
@@ -29,8 +30,7 @@ def test_flash_decode_matches_reference(window, pos_past_wrap):
 
     expect = decode_attention(q, k, v, pos, window=window)
 
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q_, k_, v_: flash_decode_attention(
             q_, k_, v_, pos, axis_name="data", total_len=L, window=window),
         mesh=mesh, in_specs=(P(), P(None, "data"), P(None, "data")),

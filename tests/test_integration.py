@@ -11,6 +11,7 @@ from repro import optim
 from repro.checkpoint import restore, save
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy, losses
+from repro.core.sharding import make_mesh
 from repro.data import cifar_like, lm_batches, token_stream
 from repro.models import build_cnn, build_model
 from repro.serverless import paper_cost_check, simulate_epoch
@@ -19,7 +20,7 @@ from repro.serverless import paper_cost_check, simulate_epoch
 def test_lm_loss_decreases():
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ts = build_train_step(model, optim.adamw(3e-3),
                           get_strategy("allreduce"), mesh)
     state = ts.init_state(jax.random.PRNGKey(0))
@@ -32,10 +33,24 @@ def test_lm_loss_decreases():
     assert np.mean(losses_seen[-5:]) < np.mean(losses_seen[:5]) - 0.3
 
 
+@pytest.mark.parametrize("strategy", ["allreduce", "mlless"])
+def test_init_state_types_are_the_steps_own(strategy):
+    """``init_state`` places every leaf, step counters included, where
+    the step returns it: the second step reuses the first's program."""
+    cfg = get_config("smollm-135m").reduced()
+    mesh = make_mesh((1, 1), ("data", "model"))
+    ts = build_train_step(build_model(cfg, remat=False), optim.adamw(1e-3),
+                          get_strategy(strategy), mesh)
+    state = ts.init_state(jax.random.PRNGKey(0))
+    toks = np.zeros((2, 16), np.int32)
+    new, _ = ts.step_fn(state, {"tokens": toks, "labels": toks})
+    assert jax.tree.map(jax.typeof, new) == jax.tree.map(jax.typeof, state)
+
+
 def test_cnn_learns_synthetic_cifar():
     cfg = get_config("mobilenet-cifar").reduced()
     model = build_cnn(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def loss_fn(params, b):
         logits, _ = model.apply(params, b)
@@ -143,16 +158,16 @@ def test_hlo_collective_parser_counts_scan_trips():
     if len(jax.devices()) < 2:
         pytest.skip("needs >1 device")
 
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
 
     def f(x):
         def body(c, _):
             return jax.lax.psum(c, "data"), None
         y, _ = jax.lax.scan(body, x, None, length=7)
         return y
-    from repro.compat import shard_map
-    sm = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                   check_vma=False, axis_names={"data"})
+    sm = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                       out_specs=P("data"), check_vma=False,
+                       axis_names={"data"})
     hlo = jax.jit(sm).lower(
         jnp.ones((2, 64), jnp.float32)).compile().as_text()
     stats = analyze_collectives(hlo)
@@ -166,7 +181,7 @@ def test_trainstate_checkpoint_resume_equivalence():
     from repro import optim
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     ts = build_train_step(model, optim.adamw(1e-3),
                           get_strategy("mlless"), mesh)
     r = np.random.RandomState(3)

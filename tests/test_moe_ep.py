@@ -1,14 +1,9 @@
 """Expert-parallel (all_to_all) MoE == local capacity-dispatch MoE."""
-import os
 import subprocess
 import sys
 import textwrap
 
-import jax
-import numpy as np
-import pytest
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+from repro.launch._subprocess import child_env
 
 
 def test_moe_ep_matches_local_dispatch():
@@ -16,21 +11,21 @@ def test_moe_ep_matches_local_dispatch():
     import jax, jax.numpy as jnp, numpy as np, dataclasses
     from jax.sharding import PartitionSpec as P
     from repro.configs.base import get_config
+    from repro.core.sharding import make_mesh
     from repro.models import moe
 
     cfg = dataclasses.replace(get_config("mixtral-8x7b").reduced(),
                               n_experts=4, experts_per_token=2)
     p = moe.moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     B, S = 8, 16
     x = 0.3 * jax.random.normal(jax.random.PRNGKey(1), (B, S, cfg.d_model))
 
-    from repro.compat import shard_map
-    local = shard_map(
+    local = jax.shard_map(
         lambda p_, x_: moe.moe_apply(p_, x_, cfg)[0],
         mesh=mesh, in_specs=(P(), P("data")), out_specs=P("data"),
         check_vma=False, axis_names={"data"})
-    ep = shard_map(
+    ep = jax.shard_map(
         lambda p_, x_: moe.moe_apply_ep(p_, x_, cfg, axis_name="data")[0],
         mesh=mesh, in_specs=(P(), P("data")), out_specs=P("data"),
         check_vma=False, axis_names={"data"})
@@ -39,11 +34,8 @@ def test_moe_ep_matches_local_dispatch():
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-5)
     print("OK")
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, timeout=560,
-                         env=env)
+                         env=child_env(4))
     assert out.returncode == 0, out.stderr[-3000:]
     assert "OK" in out.stdout

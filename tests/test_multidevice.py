@@ -1,29 +1,18 @@
 """Multi-device tests run in subprocesses (the main pytest process must
 keep the default 1-device backend — see conftest)."""
-import os
 import subprocess
 import sys
 import textwrap
 
 import pytest
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-from repro.compat import HAS_PARTIAL_MANUAL_SHARD_MAP  # noqa: E402
-
-requires_partial_manual = pytest.mark.skipif(
-    not HAS_PARTIAL_MANUAL_SHARD_MAP,
-    reason="XLA SPMD partitioner crashes on partial-manual multi-device "
-           "meshes with jax<0.5 (IsManualSubgroup check failure)")
+from repro.launch._subprocess import child_env
 
 
 def _run(code, devices=8, timeout=560):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, timeout=timeout,
-                         env=env)
+                         env=child_env(devices))
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 
@@ -32,10 +21,10 @@ def test_flash_decode_sharded():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.core.flash_decode import flash_decode_attention
+    from repro.core.sharding import make_mesh
     from repro.models.attention import decode_attention
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     B, L, KV, G, hd = 2, 64, 2, 3, 32
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(B, 1, KV*G, hd), jnp.float32)
@@ -43,7 +32,7 @@ def test_flash_decode_sharded():
     v = jnp.asarray(rs.randn(B, L, KV, hd), jnp.float32)
     for window, pos in ((None, L-1), (48, L+7)):
         expect = decode_attention(q, k, v, jnp.asarray(pos), window=window)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_, k_, v_: flash_decode_attention(
                 q_, k_, v_, jnp.asarray(pos), axis_name="data",
                 total_len=L, window=window),
@@ -56,7 +45,6 @@ def test_flash_decode_sharded():
     """)
 
 
-@requires_partial_manual
 def test_strategies_agree_across_real_data_shards():
     """4-way data parallel: allreduce == scatterreduce == PS, and dp
     sharding equals single-device training."""
@@ -65,8 +53,9 @@ def test_strategies_agree_across_real_data_shards():
     from repro.configs.base import get_config
     from repro.models import build_model
     from repro.core import build_train_step, get_strategy
+    from repro.core.sharding import make_mesh
     from repro import optim
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
     r = np.random.RandomState(0)
@@ -101,7 +90,6 @@ def test_quantized_scatterreduce_tuple_axis_parity():
     _run("""
     import numpy as np, jax, jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
     from repro.core.compression import QuantizedScatterReduce
 
     g = jnp.asarray(np.random.RandomState(0).randn(4, 1030),
@@ -113,8 +101,8 @@ def test_quantized_scatterreduce_tuple_axis_parity():
             out, resid, _ = qsr.sync([x[0]], [jnp.zeros_like(x[0])],
                                      axes)
             return out[0]
-        f = shard_map(body, mesh=mesh, in_specs=P(spec), out_specs=P(),
-                      check_vma=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=P(spec),
+                          out_specs=P(), check_vma=False)
         return np.asarray(f(g))
 
     flat = run(Mesh(np.array(jax.devices()), ("data",)), "data", "data")
@@ -146,7 +134,6 @@ def test_quantized_scatterreduce_rejects_empty_axes():
 
 
 @pytest.mark.slow
-@requires_partial_manual
 def test_dryrun_one_combo_small():
     """End-to-end dry-run driver on the real 512-device production mesh
     for the cheapest (arch, shape) pair."""

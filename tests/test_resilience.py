@@ -119,9 +119,8 @@ def test_store_partition_roundtrip():
 # survivor_mesh (validation paths run on the default 1-device backend)
 # ---------------------------------------------------------------------------
 def test_survivor_mesh_validation():
-    import jax
-    from repro.core.sharding import survivor_mesh
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.core.sharding import make_mesh, survivor_mesh
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="no axis 'pod'"):
         survivor_mesh(mesh, 0, data_axis="pod")
     with pytest.raises(ValueError, match="out of range"):
@@ -190,6 +189,7 @@ def test_checkpoint_restore_to_jax_template_is_donatable(tmp_path):
 def test_subprocess_env_and_result_parsing():
     from repro.launch import _subprocess
     env = _subprocess.child_env(6)
+    assert env["JAX_PLATFORMS"] == "cpu"
     assert env["XLA_FLAGS"].endswith("device_count=6")
     assert env["PYTHONPATH"].startswith(_subprocess.src_root())
     with pytest.raises(ValueError, match="devices"):

@@ -94,19 +94,22 @@ def test_flat_buffer_sync_matches_per_leaf_reference():
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from repro.core.sharding import make_mesh
         from repro.serverless.recovery import TrimmedMean, CoordinateMedian
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         r = np.random.RandomState(0)
         grads = {"a": jnp.asarray(r.randn(4, 8, 3), jnp.float32),
                  "b": jnp.asarray(r.randn(4, 5), jnp.bfloat16),
                  "c": jnp.asarray(r.randn(4, 1, 2, 2), jnp.float32)}
         specs = jax.tree.map(lambda g: P("data"), grads)
         for strat in (TrimmedMean(trim=1), CoordinateMedian()):
-            f = shard_map(lambda g: strat.sync(g, (), "data")[0],
-                          mesh=mesh, in_specs=(specs,), out_specs=specs)
-            fr = shard_map(lambda g: strat.sync_per_leaf(g, (), "data")[0],
-                           mesh=mesh, in_specs=(specs,), out_specs=specs)
+            f = jax.shard_map(lambda g: strat.sync(g, (), "data")[0],
+                              mesh=mesh, in_specs=(specs,),
+                              out_specs=specs, check_vma=False)
+            fr = jax.shard_map(
+                lambda g: strat.sync_per_leaf(g, (), "data")[0],
+                mesh=mesh, in_specs=(specs,), out_specs=specs,
+                check_vma=False)
             a, b = f(grads), fr(grads)
             for k in grads:
                 assert a[k].dtype == grads[k].dtype
@@ -116,13 +119,8 @@ def test_flat_buffer_sync_matches_per_leaf_reference():
                     np.asarray(b[k], np.float32), rtol=1e-5, atol=1e-5)
         print("OK")
     """)
-    import os
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable, "-c", code], env=env,
+    from repro.launch._subprocess import child_env
+    out = subprocess.run([sys.executable, "-c", code], env=child_env(4),
                          capture_output=True, text=True, timeout=560)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout

@@ -8,12 +8,12 @@ import numpy as np
 from hypothesis import given, settings
 from jax.sharding import PartitionSpec as P
 
-from repro.core.sharding import cache_pspecs, leaf_pspec
+from repro.core.sharding import cache_pspecs, leaf_pspec, make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 class _FakeMesh:

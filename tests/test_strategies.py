@@ -17,6 +17,7 @@ import pytest
 from repro import optim
 from repro.configs.base import get_config
 from repro.core import build_train_step, get_strategy
+from repro.core.sharding import make_mesh
 from repro.core.strategies import MLLess
 from repro.models import build_model
 
@@ -25,7 +26,7 @@ from repro.models import build_model
 def setting():
     cfg = get_config("smollm-135m").reduced()
     model = build_model(cfg, remat=False)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     r = np.random.RandomState(1)
     batch = {"tokens": r.randint(0, cfg.vocab_size, (8, 32)).astype(
         np.int32)}
@@ -67,6 +68,20 @@ def test_mlless_filters_and_converges_direction(setting):
     frac = float(metrics["significant_fraction"])
     assert 0.0 < frac < 1.0  # actually filtering something
     assert np.isfinite(float(metrics["loss"]))
+
+
+def test_mlless_kernel_path_matches_inline_path(setting):
+    """The Pallas block-significance path (interpret mode here), run
+    inside the train step's shard_map with ``model`` left Auto, filters
+    exactly as the inline jnp path does."""
+    cfg, model, mesh, batch = setting
+    inline, m0 = _run(model, mesh, batch, MLLess(threshold=1.0,
+                                                 use_kernel=False))
+    kernel, m1 = _run(model, mesh, batch, MLLess(threshold=1.0,
+                                                 use_kernel=True))
+    assert float(m1["significant_fraction"]) == float(
+        m0["significant_fraction"])
+    np.testing.assert_allclose(inline, kernel, atol=1e-6)
 
 
 def test_strategy_comm_bytes_ordering():
