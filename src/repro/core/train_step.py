@@ -114,6 +114,14 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
             return losses.softmax_cross_entropy(
                 logits, batch["labels"]) + aux
 
+    # Name scopes a profile reads to split the step's device time into
+    # phases: "forward" (differentiated, so the backward reads
+    # transpose(jvp(forward)) and the remat recompute
+    # .../rematted_computation/...), "sync" and "optimizer".
+    def scoped_loss(params, batch):
+        with jax.named_scope("forward"):
+            return loss_fn(params, batch)
+
     # ---------------- parameter pspecs / fsdp bookkeeping ----------------
     example_params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     pspecs = sharding.param_pspecs(example_params, mesh, fsdp=fsdp,
@@ -171,7 +179,7 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
 
             def acc_body(i, carry):
                 acc, _ = carry
-                l, g = jax.value_and_grad(loss_fn)(params, mb_slice(i))
+                l, g = jax.value_and_grad(scoped_loss)(params, mb_slice(i))
                 return (jax.tree.map(
                     lambda a, b: a + b.astype(jnp.float32), acc, g), l)
             zeros = jax.tree.map(
@@ -180,25 +188,27 @@ def build_train_step(model, optimizer: Optimizer, strategy: Strategy,
                 0, Ke, acc_body, (zeros, jnp.zeros((), jnp.float32)))
             grads = jax.tree.map(lambda g: g / Ke, gsum)
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            loss, grads = jax.value_and_grad(scoped_loss)(params, batch)
 
         # --- split FSDP (pre-reduced) leaves from strategy-synced leaves
         gleaves, gdef = jax.tree.flatten(grads)
         sync_leaves = [g for g, m in zip(gleaves, fsdp_mask) if not m]
-        synced, new_strat_local, info = strategy.sync(
-            sync_leaves, strat_local, data_axes if len(data_axes) > 1
-            else data_axes[0])
-        out_leaves, si = [], 0
-        for g, m in zip(gleaves, fsdp_mask):
-            if m:
-                out_leaves.append(g / W)   # reduce-scatter sum -> mean
-            else:
-                out_leaves.append(synced[si])
-                si += 1
+        with jax.named_scope("sync"):
+            synced, new_strat_local, info = strategy.sync(
+                sync_leaves, strat_local, data_axes if len(data_axes) > 1
+                else data_axes[0])
+            out_leaves, si = [], 0
+            for g, m in zip(gleaves, fsdp_mask):
+                if m:
+                    out_leaves.append(g / W)   # reduce-scatter sum -> mean
+                else:
+                    out_leaves.append(synced[si])
+                    si += 1
         grads = jax.tree.unflatten(gdef, out_leaves)
 
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
         metrics = {"loss": jax.lax.pmean(loss, data_axes if
                                          len(data_axes) > 1
                                          else data_axes[0]),

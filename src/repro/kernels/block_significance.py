@@ -40,6 +40,7 @@ def block_norms(blocks, *, tile_rows=256, interpret=None):
         out_specs=pl.BlockSpec((tile_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         interpret=interpret,
+        name="block_significance_norms",
     )(blocks)
     return out[:n, 0]
 
@@ -76,5 +77,6 @@ def masked_filter(blocks, mask, *, tile_rows=256, interpret=None):
         out_shape=[jax.ShapeDtypeStruct((np_, b), blocks.dtype),
                    jax.ShapeDtypeStruct((np_, b), blocks.dtype)],
         interpret=interpret,
+        name="block_significance_filter",
     )(blocks, m2)
     return kept[:n], resid[:n]

@@ -63,6 +63,7 @@ def fused_adamw_flat(g, m, v, p, c1, c2, *, lr, b1, b2, eps, wd,
         out_specs=[pl.BlockSpec((rows, cols), lambda i: (i, 0))] * 3,
         out_shape=[jax.ShapeDtypeStruct((R, cols), jnp.float32)] * 3,
         interpret=interpret,
+        name="fused_adamw",
     )(g2, m2, v2, p2, cvec)
     unflat = lambda x: x.reshape(-1)[:n]
     return unflat(u2), unflat(mo2), unflat(vo2)

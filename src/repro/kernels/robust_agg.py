@@ -174,6 +174,10 @@ def _rowstat_kernel(x_ref, o_ref, *, stat, trim):
     o_ref[...] = out[None, :]
 
 
+_ROWSTAT_NAMES = {"trim": "robust_agg_trimmed_mean",
+                  "median": "robust_agg_median"}
+
+
 def _rowstat_pallas(flat, tile_d, interpret, *, stat, trim=0):
     W = flat.shape[0]
     padded, tile, n_tiles = _pad_tiles(flat, tile_d)
@@ -185,6 +189,7 @@ def _rowstat_pallas(flat, tile_d, interpret, *, stat, trim=0):
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, padded.shape[1]), jnp.float32),
         interpret=interpret,
+        name=_ROWSTAT_NAMES[stat],
     )(padded)
     return out[0]
 
@@ -256,6 +261,7 @@ def krum_pairwise(stacked, *, tile_d: int = _DEFAULT_TILE_D,
         out_specs=pl.BlockSpec((W, W), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((W, W), jnp.float32),
         interpret=_auto_interpret(interpret),
+        name="robust_agg_krum_sqdist",
     )(padded)
 
 
@@ -319,6 +325,7 @@ def weiszfeld_step(stacked, z, floor, *, row_sqnorms=None,
         out_specs=pl.BlockSpec((W, 1), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((W, 1), jnp.float32),
         interpret=interp,
+        name="robust_agg_weiszfeld_sqdist",
     )(padded, zp)
     w = 1.0 / jnp.maximum(jnp.sqrt(sq), floor)       # (W, 1)
     wsum = pl.pallas_call(
@@ -329,5 +336,6 @@ def weiszfeld_step(stacked, z, floor, *, row_sqnorms=None,
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, padded.shape[1]), jnp.float32),
         interpret=interp,
+        name="robust_agg_weiszfeld_wsum",
     )(padded, w)
     return wsum[0, :D] / jnp.sum(w)

@@ -113,5 +113,6 @@ def swa_attention_fwd(q, k, v, *, window=None, causal=True,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, S, hd), q.dtype),
         interpret=interpret,
+        name="swa_attention",
     )(qh, kh, vh)
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)

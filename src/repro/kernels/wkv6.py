@@ -87,4 +87,5 @@ def wkv6_chunked(r, k, v, logw, u, *, chunk=64, interpret=None):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, T, H, N), r.dtype),
         interpret=interpret,
+        name="wkv6",
     )(r, k, v, logw, u)

@@ -8,6 +8,10 @@ Examples:
   # the paper's CNN x strategy matrix
   PYTHONPATH=src python -m repro.launch.train --arch mobilenet-cifar \
       --reduced --strategy mlless --steps 100
+
+  # profile every step after the first (the compile) into a directory
+  PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
+      --reduced --steps 10 --profile /tmp/train-profile
 """
 from __future__ import annotations
 
@@ -45,6 +49,9 @@ def main():
     ap.add_argument("--fsdp", action="store_true")
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--fused-optimizer", action="store_true")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="write a profiler trace of every step after the "
+                         "first (the compile) to DIR")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -95,14 +102,31 @@ def main():
           f"mesh={mesh.shape}")
 
     t0 = time.time()
-    for step, batch in zip(range(args.steps), batches()):
-        state, metrics = ts.step_fn(state, batch)
+    feed = batches()
+    profiling = False
+    for step in range(args.steps):
+        if args.profile and step == 1:
+            jax.profiler.start_trace(args.profile)
+            profiling = True
+        # the span names of chipbench's harness, so that its trace
+        # reduction reads this trace too
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            with jax.profiler.TraceAnnotation("input"):
+                batch = next(feed, None)
+            if batch is None:
+                break
+            with jax.profiler.TraceAnnotation("dispatch"):
+                state, metrics = ts.step_fn(state, batch)
         if step % 10 == 0 or step == args.steps - 1:
             extra = "".join(
                 f" {k}={float(v):.3f}" for k, v in metrics.items()
                 if k not in ("loss", "step"))
             print(f"step {step:4d}  loss {float(metrics['loss']):.4f}"
                   f"{extra}  ({time.time() - t0:.1f}s)")
+    if profiling:
+        jax.block_until_ready(state)
+        jax.profiler.stop_trace()
+        print(f"profile of the steps after the first in {args.profile}")
     if args.checkpoint:
         save(args.checkpoint, state["params"])
         print(f"saved params to {args.checkpoint}")

@@ -278,10 +278,15 @@ def chunked_attention(q, k, v, *, causal=True, window=None,
 
     q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd) with H % KV == 0.
     ``window``: query at position i attends to [i-window+1, i].
+
+    Runs under the name scope ``attention``, which a profile reads to
+    attribute device time to the attention core (scores, online softmax,
+    values) in the forward, the recompute and the backward.
     """
-    if pallas_fn is not None and causal and q.shape[1] == k.shape[1]:
-        return pallas_fn(q, k, v, window=window)
-    return _flash(q, k, v, causal, window, q_chunk, kv_chunk)
+    with jax.named_scope("attention"):
+        if pallas_fn is not None and causal and q.shape[1] == k.shape[1]:
+            return pallas_fn(q, k, v, window=window)
+        return _flash(q, k, v, causal, window, q_chunk, kv_chunk)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,8 @@ The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, and pytest-xdist imports
 this file in every worker.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -119,30 +121,108 @@ def test_robust_agg_kernels_compile(one_chip, sizes, entry, workers):
     assert "tpu_custom_call" in txt
 
 
-@pytest.mark.parametrize("strategy,fused", [("mlless", False),
-                                             ("allreduce", True)])
-def test_train_step_compiles_with_kernel(one_chip, monkeypatch, strategy,
-                                         fused):
-    """A Pallas kernel inside the train step's shard_map (data manual,
-    ``model`` Auto) must reach the chip's compiler as a Mosaic call:
-    MLLess's block significance, and the fused AdamW update."""
+@pytest.fixture(scope="module")
+def train_step_text(one_chip):
+    """The compiled reduced train step, once per (strategy, fused)."""
     from repro import optim
     from repro.core import build_train_step, get_strategy
     from repro.core.sharding import make_mesh
     from repro.kernels import ops
 
-    # the code asks the backend (the CPU here); steer it to the TPU path
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
-    cfg = CFG.reduced()
-    mesh = make_mesh((1, 1), ("data", "model"),
-                     devices=[next(iter(one_chip.device_set))])
-    ts = build_train_step(build_model(cfg, remat=False),
-                          optim.adamw(1e-3, use_fused=fused),
-                          get_strategy(strategy), mesh)
-    batch = {k: jax.ShapeDtypeStruct((2, 128), jnp.int32, sharding=s)
-             for k, s in ts.batch_shardings.items()}
-    txt = ts.step_fn.lower(ts.state_sds(), batch).compile().as_text()
-    assert "tpu_custom_call" in txt
+    texts = {}
+
+    def text(strategy, fused):
+        if (strategy, fused) in texts:
+            return texts[strategy, fused]
+        with pytest.MonkeyPatch.context() as mp:
+            # the code asks the backend (the CPU here); steer it to the
+            # TPU path
+            mp.setattr(ops, "default_interpret", lambda: False)
+            cfg = CFG.reduced()
+            mesh = make_mesh((1, 1), ("data", "model"),
+                             devices=[next(iter(one_chip.device_set))])
+            ts = build_train_step(build_model(cfg, remat=False),
+                                  optim.adamw(1e-3, use_fused=fused),
+                                  get_strategy(strategy), mesh)
+            batch = {k: jax.ShapeDtypeStruct((2, 128), jnp.int32,
+                                             sharding=s)
+                     for k, s in ts.batch_shardings.items()}
+            texts[strategy, fused] = ts.step_fn.lower(
+                ts.state_sds(), batch).compile().as_text()
+        return texts[strategy, fused]
+    return text
+
+
+@pytest.mark.parametrize("strategy,fused", [("mlless", False),
+                                             ("allreduce", True)])
+def test_train_step_compiles_with_kernel(train_step_text, strategy, fused):
+    """A Pallas kernel inside the train step's shard_map (data manual,
+    ``model`` Auto) must reach the chip's compiler as a Mosaic call:
+    MLLess's block significance, and the fused AdamW update."""
+    assert "tpu_custom_call" in train_step_text(strategy, fused)
+
+
+@pytest.mark.parametrize("strategy,fused,kernels,scope", [
+    ("mlless", False, ("block_significance_norms",), "sync"),
+    ("allreduce", True, ("fused_adamw",), "optimizer")])
+def test_train_step_kernels_are_named_in_their_phase(train_step_text,
+                                                     strategy, fused, kernels,
+                                                     scope):
+    """Each Mosaic call is an instruction named after its kernel, so a
+    trace names it, and sits under its call site's name scope."""
+    txt = train_step_text(strategy, fused)
+    for kernel in kernels:
+        line = next((ln for ln in txt.splitlines()
+                     if re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = ", ln)),
+                    None)
+        assert line is not None, kernel
+        assert "tpu_custom_call" in line
+        assert f"/{scope}/" in line, line[:300]
+
+
+@pytest.mark.parametrize("kernel", [
+    "block_significance_norms", "block_significance_filter", "fused_adamw",
+    "robust_agg_trimmed_mean", "robust_agg_median", "robust_agg_krum_sqdist",
+    "robust_agg_weiszfeld_sqdist", "robust_agg_weiszfeld_wsum",
+    "swa_attention"])
+def test_kernel_instruction_carries_its_name(one_chip, kernel):
+    W, D, n = 4, 8192, 4096
+    f32 = jnp.float32
+    calls = {
+        "block_significance_norms": (
+            lambda x: bs.block_norms(x, interpret=False), ((64, 256), f32)),
+        "block_significance_filter": (
+            lambda x, m: bs.masked_filter(x, m, interpret=False),
+            ((64, 256), f32), ((64,), jnp.bool_)),
+        "fused_adamw": (
+            lambda g, m, v, p, c: fa.fused_adamw_flat(
+                g, m, v, p, c[0], c[1], lr=1e-3, b1=0.9, b2=0.95, eps=1e-8,
+                wd=0.01, interpret=False),
+            ((n,), jnp.bfloat16), ((n,), f32), ((n,), f32),
+            ((n,), jnp.bfloat16), ((2,), f32)),
+        "robust_agg_trimmed_mean": (
+            lambda x: robust_agg.trimmed_mean(x, 1, interpret=False),
+            ((W, D), f32)),
+        "robust_agg_median": (
+            lambda x: robust_agg.coordinate_median(x, interpret=False),
+            ((W + 1, D), f32)),
+        "robust_agg_krum_sqdist": (
+            lambda x: robust_agg.krum_pairwise(x, interpret=False),
+            ((W, D), f32)),
+        "robust_agg_weiszfeld_sqdist": (
+            lambda x, z: robust_agg.weiszfeld_step(x, z, 1e-12,
+                                                   interpret=False),
+            ((W, D), f32), ((D,), f32)),
+        "swa_attention": (
+            lambda q, k, v: swa.swa_attention_fwd(q, k, v, interpret=False),
+            ((1, 512, CFG.n_heads, CFG.head_dim), jnp.bfloat16),
+            ((1, 512, CFG.n_kv_heads, CFG.head_dim), jnp.bfloat16),
+            ((1, 512, CFG.n_kv_heads, CFG.head_dim), jnp.bfloat16)),
+    }
+    calls["robust_agg_weiszfeld_wsum"] = calls["robust_agg_weiszfeld_sqdist"]
+    fn, *shapes = calls[kernel]
+    txt = _compiled_text(fn, *shapes, sharding=one_chip)
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", txt), kernel
 
 
 @pytest.mark.parametrize("window", [None, 1024])
