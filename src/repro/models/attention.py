@@ -60,13 +60,24 @@ def project_qkv(p, x, cfg):
 # wrapped in a custom VJP so the backward never materializes O(S^2)
 # residuals (the fix that makes 4k-train / 32k-prefill fit in HBM).
 # ---------------------------------------------------------------------------
-def _block_mask(q_pos, kv_pos, Sq, Skv, causal, window):
+def _mask_bias(q_pos, kv_pos, Sq, Skv, causal, window):
+    """Additive f32 bias of a score block, shaped to add to
+    ``(B, q_chunk, G, KV, kv_chunk)`` scores: 0 where the query at
+    ``q_pos`` may see the key at ``kv_pos`` (both inside their sequences,
+    causal and in the window), ``NEG_INF`` elsewhere.
+
+    The bias stays 2-D and broadcasts in the add.  A ``jnp.where`` over
+    the scores would broadcast the mask and its fill to the full block;
+    neither depends on a parameter, so under differentiation JAX hoists
+    them out of the layer scan as stacks of full blocks that every
+    layer's forward then copies back.
+    """
     mask = (kv_pos[None, :] <= Skv - 1) & (q_pos[:, None] <= Sq - 1)
     if causal:
         mask = mask & (kv_pos[None, :] <= q_pos[:, None])
     if window is not None:
         mask = mask & (kv_pos[None, :] > q_pos[:, None] - window)
-    return mask
+    return jnp.where(mask, 0.0, NEG_INF)[None, :, None, None, :]
 
 
 def _relevant(q_lo, q_hi, k_lo, k_hi, causal, window):
@@ -111,8 +122,7 @@ def _flash_fwd_impl(q, k, v, causal, window, q_chunk, kv_chunk):
             def attend(_):
                 s = jnp.einsum("bqkgh,bskh->bqgks", qblk, kblk,
                                preferred_element_type=jnp.float32) * scale
-                mask = _block_mask(q_pos, kv_pos, Sq, Skv, causal, window)
-                s = jnp.where(mask[None, :, None, None, :], s, NEG_INF)
+                s = s + _mask_bias(q_pos, kv_pos, Sq, Skv, causal, window)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1))
                 p = jnp.exp(s - m_new[..., None])
                 corr = jnp.exp(m - m_new)
@@ -175,8 +185,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, window, q_chunk,
         kv_pos = k_lo + jnp.arange(kv_chunk)
         s = jnp.einsum("bqkgh,bskh->bqgks", qblk, kblk,
                        preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(q_pos, kv_pos, Sq, Skv, causal, window)
-        s = jnp.where(mask[None, :, None, None, :], s, NEG_INF)
+        s = s + _mask_bias(q_pos, kv_pos, Sq, Skv, causal, window)
         return jnp.exp(s - lseblk.transpose(0, 1, 2, 3)[..., None])
 
     # ---- pass 1: dq per q block ----
