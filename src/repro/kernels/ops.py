@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import block_significance as _bs
+from repro.kernels import flash_attention as _flash
 from repro.kernels import fused_adamw as _fa
 from repro.kernels import ref as _ref
 from repro.kernels import swa_attention as _swa
@@ -85,6 +86,16 @@ _swa_core.defvjp(_swa_fwd, _swa_bwd)
 
 def swa_attention(q, k, v, *, window=None, causal=True):
     return _swa_core(q, k, v, window, causal)
+
+
+# ---------------------------------------------------------------------------
+# causal flash attention (forward and fused backward)
+# ---------------------------------------------------------------------------
+def flash_attention(q, k, v, *, window=None):
+    """Causal self-attention on whole operands (``flash_attention.py``);
+    shapes as ``ref.flash_attention``."""
+    return _whole_on_auto_axes(
+        functools.partial(_flash.flash_attention, window=window), q, k, v)
 
 
 # ---------------------------------------------------------------------------

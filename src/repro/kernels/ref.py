@@ -27,6 +27,13 @@ def swa_attention(q, k, v, *, window=None, causal=True):
     return o.reshape(B, S, H, hd).astype(q.dtype)
 
 
+def flash_attention(q, k, v, *, window=None):
+    """Causal (optionally windowed) attention over the whole sequence:
+    the oracle of ``ops.flash_attention``, float32 throughout."""
+    f32 = lambda x: x.astype(jnp.float32)
+    return swa_attention(f32(q), f32(k), f32(v), window=window, causal=True)
+
+
 def block_norms(blocks):
     return jnp.sum(blocks.astype(jnp.float32) ** 2, axis=1)
 

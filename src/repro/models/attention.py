@@ -4,7 +4,9 @@ causal / sliding-window masking, and single-token KV-cache decode.
 The chunked implementation is the default lowering path (pure ``jnp`` +
 ``lax.scan`` with online softmax => O(seq) live memory).  Out-of-window /
 fully-masked KV chunks are skipped with ``lax.cond`` so sliding-window
-attention does O(S*W) work, not O(S^2).  The Pallas kernel in
+attention does O(S*W) work, not O(S^2).  On TPU, causal self-attention
+runs the Pallas flash kernels of ``repro.kernels.flash_attention``
+instead (:func:`uses_flash_kernel` says where).  The Pallas kernel in
 ``repro.kernels.swa_attention`` is the drop-in optimized path
 (``use_pallas=True`` in :func:`repro.models.transformer.build_model`).
 """
@@ -295,7 +297,32 @@ def chunked_attention(q, k, v, *, causal=True, window=None,
     with jax.named_scope("attention"):
         if pallas_fn is not None and causal and q.shape[1] == k.shape[1]:
             return pallas_fn(q, k, v, window=window)
+        if uses_flash_kernel(q, k, causal):
+            from repro.kernels import ops as kops
+            return kops.flash_attention(q, k, v, window=window)
         return _flash(q, k, v, causal, window, q_chunk, kv_chunk)
+
+
+def uses_flash_kernel(q, k, causal) -> bool:
+    """Whether ``chunked_attention`` runs the Pallas flash kernels
+    (``kernels/flash_attention.py``) rather than ``_flash``: on TPU, for
+    causal self-attention whose length is a multiple of the smallest
+    tile and whose working set fits the kernels' VMEM, where no Auto axis
+    of the ambient mesh would have GSPMD split the kernel (inside the
+    train step the data axes are manual, so the kernel sees its own
+    device's batch).  Pallas is imported only where attention runs: its
+    import adds over a second to a process's start."""
+    if not causal or k.shape[1] != q.shape[1]:
+        return False
+    from repro.kernels import flash_attention as kflash
+    from repro.kernels import ops as kops
+    B, S, H, hd = q.shape
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = [n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto]
+    return (not kops.default_interpret() and S % kflash.MIN_BLOCK == 0
+            and kflash.flash_attention_fits(S, H // k.shape[2], hd, q.dtype)
+            and all(mesh.shape[n] == 1 for n in auto))
 
 
 # ---------------------------------------------------------------------------

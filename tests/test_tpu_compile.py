@@ -11,6 +11,7 @@ The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, and pytest-xdist imports
 this file in every worker.
 """
+import dataclasses
 import re
 
 import jax
@@ -21,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
 from repro.kernels import block_significance as bs
+from repro.kernels import flash_attention as kflash
 from repro.kernels import fused_adamw as fa
 from repro.kernels import robust_agg
 from repro.kernels import swa_attention as swa
@@ -184,7 +186,7 @@ def test_train_step_kernels_are_named_in_their_phase(train_step_text,
     "block_significance_norms", "block_significance_filter", "fused_adamw",
     "robust_agg_trimmed_mean", "robust_agg_median", "robust_agg_krum_sqdist",
     "robust_agg_weiszfeld_sqdist", "robust_agg_weiszfeld_wsum",
-    "swa_attention"])
+    "swa_attention", "flash_attention_fwd", "flash_attention_bwd"])
 def test_kernel_instruction_carries_its_name(one_chip, kernel):
     W, D, n = 4, 8192, 4096
     f32 = jnp.float32
@@ -220,6 +222,20 @@ def test_kernel_instruction_carries_its_name(one_chip, kernel):
             ((1, 512, CFG.n_kv_heads, CFG.head_dim), jnp.bfloat16)),
     }
     calls["robust_agg_weiszfeld_wsum"] = calls["robust_agg_weiszfeld_sqdist"]
+    heads = calls["swa_attention"][1:]
+    calls["flash_attention_fwd"] = (
+        lambda q, k, v: kflash.flash_attention(q, k, v, interpret=False),
+        *heads)
+    # the backward called as such: under jax.grad the instruction's name
+    # takes the transforms' prefix (``transpose_jvp_...``)
+    group = (1, CFG.n_kv_heads, CFG.n_heads // CFG.n_kv_heads, 512)
+    calls["flash_attention_bwd"] = (
+        lambda q, k, v, do, lse, di: kflash._backward(
+            q, k, v, do, lse, di, None, 512, 512, False),
+        ((*group, CFG.head_dim), jnp.bfloat16),
+        ((1, CFG.n_kv_heads, 512, CFG.head_dim), jnp.bfloat16),
+        ((1, CFG.n_kv_heads, 512, CFG.head_dim), jnp.bfloat16),
+        ((*group, CFG.head_dim), jnp.bfloat16), (group, f32), (group, f32))
     fn, *shapes = calls[kernel]
     txt = _compiled_text(fn, *shapes, sharding=one_chip)
     assert re.search(rf"%{kernel}(\.\d+)? = .*custom-call\(", txt), kernel
@@ -235,3 +251,61 @@ def test_swa_attention_compiles(one_chip, window):
             q_, k_, v_, window=window, interpret=False),
         q, kv, kv, sharding=one_chip)
     assert "tpu_custom_call" in txt
+
+
+@pytest.fixture(scope="module")
+def lm_step_text(one_chip):
+    """The compiled train step of a two-layer smollm-135m (its widths and
+    head layout, remat over the layer scan) at batch 2 x 1024, where the
+    chunked attention's score block is (2, 512, 3, 3, 512)."""
+    from repro import optim
+    from repro.core import build_train_step, get_strategy
+    from repro.core.sharding import make_mesh
+    from repro.kernels import ops
+
+    with pytest.MonkeyPatch.context() as mp:
+        # the code asks the backend (the CPU here); steer it to the TPU path
+        mp.setattr(ops, "default_interpret", lambda: False)
+        cfg = dataclasses.replace(CFG, n_layers=2, vocab_size=512)
+        mesh = make_mesh((1, 1), ("data", "model"),
+                         devices=[next(iter(one_chip.device_set))])
+        ts = build_train_step(build_model(cfg, remat=True),
+                              optim.adamw(1e-3), get_strategy("allreduce"),
+                              mesh)
+        batch = {k: jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=s)
+                 for k, s in ts.batch_shardings.items()}
+        return ts.step_fn.lower(ts.state_sds(), batch).compile().as_text()
+
+
+def _phase(op_name):
+    if "rematted_computation" in op_name:
+        return "recompute"
+    if "transpose(jvp(forward))" in op_name:
+        return "backward"
+    return "forward" if "jvp(forward)" in op_name else None
+
+
+@pytest.mark.parametrize("kernel,phase", [
+    ("flash_attention_fwd", "forward"), ("flash_attention_fwd", "recompute"),
+    ("flash_attention_bwd", "backward")])
+def test_train_step_attention_is_the_flash_kernel(lm_step_text, kernel,
+                                                  phase):
+    """On TPU the LM step's attention is a Mosaic call named after its
+    kernel, under the ``attention`` scope, in each pass that runs it."""
+    found = set()
+    for line in lm_step_text.splitlines():
+        if not re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = ", line):
+            continue
+        assert "tpu_custom_call" in line
+        op = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "/attention/" in op, op
+        found.add(_phase(op))
+    assert phase in found, found
+
+
+def test_train_step_holds_no_score_block(lm_step_text):
+    """No fusion of the step computes over a whole (B, 512, G, KV, 512)
+    f32 score block, as the chunked attention's dots do."""
+    blocks = [ln[:120] for ln in lm_step_text.splitlines()
+              if " fusion(" in ln and "f32[2,512,3,3,512]" in ln]
+    assert not blocks, blocks
