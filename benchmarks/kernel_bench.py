@@ -22,8 +22,9 @@ Two sections, recorded in ``BENCH_kernels.json``:
 
      Off-TPU the production path is the kernel's fused-jnp twin where
      one exists (the robust-aggregation set, ``interpret=None``
-     auto-dispatch) or the best jnp formulation the repo ships (swa ->
-     ``models.attention.chunked_attention``).  Kernels whose CPU
+     auto-dispatch) or the best jnp formulation the repo ships (windowed
+     causal attention -> ``models.attention.chunked_attention``, against
+     ``ref.flash_attention``).  Kernels whose CPU
      production path IS the oracle (fused_adamw, wkv6, block_norms,
      masked_filter — their win is Mosaic-only) time jit(ref) against
      itself and carry a noise-tolerant parity-class floor of 0.5x;
@@ -164,7 +165,7 @@ def kernel_cases(quick: bool):
     S = 1024 if quick else 2048
     win = min(smollm.window, S // 4)
     cases.append(dict(
-        kernel="swa_attention_fwd", config="smollm-135m", B=1, S=S,
+        kernel="chunked_attention", config="smollm-135m", B=1, S=S,
         H=smollm.n_heads, KV=smollm.n_kv_heads, hd=smollm.head_dim,
         window=win,
         trunc=f"window capped {smollm.window} -> {win} (S={S})",
@@ -238,7 +239,7 @@ def _build(case, rng):
         fn = lambda *a: ref.fused_adamw_flat(*a, **kw)
         return (fn, fn, (g, m, v, p, jnp.float32(0.1), jnp.float32(0.05)),
                 7 * n * f4)
-    if k == "swa_attention_fwd":
+    if k == "chunked_attention":
         from repro.models.attention import chunked_attention
         from repro.kernels import ref as _r
         B, S, H, KV, hd, win = (case[x] for x in
@@ -251,8 +252,8 @@ def _build(case, rng):
                                              dtype=np.float32))
         return (lambda q_, k_, v_: chunked_attention(
                     q_, k_, v_, window=win, causal=True),
-                lambda q_, k_, v_: _r.swa_attention(q_, k_, v_,
-                                                    window=win),
+                lambda q_, k_, v_: _r.flash_attention(q_, k_, v_,
+                                                      window=win),
                 (q, kk, vv), (2 * B * S * H * hd
                               + 2 * B * S * KV * hd) * f4)
     if k == "wkv6_chunked":

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``swa_attention`` carries a custom VJP whose backward recomputes
-attention with the pure-jnp reference (flash-style recompute — no
-O(S^2) residuals saved), so the kernel is usable inside ``jax.grad``.
+``flash_attention`` is causal self-attention on TPU, a forward kernel
+and one fused backward kernel (``flash_attention.py``);
+``models.attention.chunked_attention`` selects it there.
 
 ``default_interpret()`` is the shared backend auto-detect every kernel
 module resolves its ``interpret=None`` default through: Mosaic lowering
@@ -22,8 +22,6 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import block_significance as _bs
 from repro.kernels import flash_attention as _flash
 from repro.kernels import fused_adamw as _fa
-from repro.kernels import ref as _ref
-from repro.kernels import swa_attention as _swa
 
 
 def default_interpret() -> bool:  # repro: allow[kernel-ref-parity] -- backend helper, not a kernel
@@ -53,39 +51,6 @@ def _whole_on_auto_axes(fn, *args):
     return jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * len(args),
                          out_specs=P(), axis_names=auto,
                          check_vma=False)(*args)
-
-
-# ---------------------------------------------------------------------------
-# sliding-window flash attention
-# ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _swa_core(q, k, v, window, causal):
-    S = q.shape[1]
-    qb = 256 if S % 256 == 0 else (128 if S % 128 == 0 else S)
-    kb = qb
-    return _swa.swa_attention_fwd(q, k, v, window=window, causal=causal,
-                                  q_block=qb, kv_block=kb)
-
-
-def _swa_fwd(q, k, v, window, causal):
-    return _swa_core(q, k, v, window, causal), (q, k, v)
-
-
-def _swa_bwd(window, causal, res, g):
-    # memory-light backward: the chunked flash bwd from the model library
-    from repro.models import attention as _att
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _att.chunked_attention(q_, k_, v_, window=window,
-                                                  causal=causal), q, k, v)
-    return vjp(g)
-
-
-_swa_core.defvjp(_swa_fwd, _swa_bwd)
-
-
-def swa_attention(q, k, v, *, window=None, causal=True):
-    return _swa_core(q, k, v, window, causal)
 
 
 # ---------------------------------------------------------------------------

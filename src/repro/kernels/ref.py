@@ -5,8 +5,10 @@ import jax
 import jax.numpy as jnp
 
 
-def swa_attention(q, k, v, *, window=None, causal=True):
-    """Naive O(S^2) masked softmax attention. Shapes as the kernel."""
+def flash_attention(q, k, v, *, window=None):
+    """Naive O(S^2) causal (optionally windowed) softmax attention over
+    the whole sequence, float32 throughout: the oracle of
+    ``ops.flash_attention``.  q: (B, S, H, hd); k, v: (B, S, KV, hd)."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -16,22 +18,13 @@ def swa_attention(q, k, v, *, window=None, causal=True):
     s = jnp.einsum("bqkgh,bskh->bkgqs", qr, kf) / (hd ** 0.5)
     qpos = jnp.arange(S)[:, None]
     kpos = jnp.arange(S)[None, :]
-    mask = jnp.ones((S, S), bool)
-    if causal:
-        mask &= kpos <= qpos
+    mask = kpos <= qpos
     if window is not None:
         mask &= kpos > qpos - window
     s = jnp.where(mask[None, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskh->bqkgh", p, vf)
-    return o.reshape(B, S, H, hd).astype(q.dtype)
-
-
-def flash_attention(q, k, v, *, window=None):
-    """Causal (optionally windowed) attention over the whole sequence:
-    the oracle of ``ops.flash_attention``, float32 throughout."""
-    f32 = lambda x: x.astype(jnp.float32)
-    return swa_attention(f32(q), f32(k), f32(v), window=window, causal=True)
+    return o.reshape(B, S, H, hd)
 
 
 def block_norms(blocks):

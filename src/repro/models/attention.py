@@ -1,14 +1,13 @@
 """Attention: GQA projections, chunked (flash-style) softmax attention with
 causal / sliding-window masking, and single-token KV-cache decode.
 
-The chunked implementation is the default lowering path (pure ``jnp`` +
-``lax.scan`` with online softmax => O(seq) live memory).  Out-of-window /
-fully-masked KV chunks are skipped with ``lax.cond`` so sliding-window
-attention does O(S*W) work, not O(S^2).  On TPU, causal self-attention
-runs the Pallas flash kernels of ``repro.kernels.flash_attention``
-instead (:func:`uses_flash_kernel` says where).  The Pallas kernel in
-``repro.kernels.swa_attention`` is the drop-in optimized path
-(``use_pallas=True`` in :func:`repro.models.transformer.build_model`).
+:func:`chunked_attention` has one lowering per platform.  On TPU, causal
+self-attention (windowed or not) runs the Pallas flash kernels of
+``repro.kernels.flash_attention`` (:func:`uses_flash_kernel` says
+where).  Everywhere else it runs ``_flash``: pure ``jnp`` +
+``lax.scan`` with online softmax => O(seq) live memory, with
+out-of-window / fully-masked KV chunks skipped by ``lax.cond`` so
+sliding-window attention does O(S*W) work, not O(S^2).
 """
 from __future__ import annotations
 
@@ -284,7 +283,7 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def chunked_attention(q, k, v, *, causal=True, window=None,
-                      q_chunk=512, kv_chunk=512, pallas_fn=None):
+                      q_chunk=512, kv_chunk=512):
     """Flash attention (see module docstring).
 
     q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd) with H % KV == 0.
@@ -295,8 +294,6 @@ def chunked_attention(q, k, v, *, causal=True, window=None,
     values) in the forward, the recompute and the backward.
     """
     with jax.named_scope("attention"):
-        if pallas_fn is not None and causal and q.shape[1] == k.shape[1]:
-            return pallas_fn(q, k, v, window=window)
         if uses_flash_kernel(q, k, causal):
             from repro.kernels import ops as kops
             return kops.flash_attention(q, k, v, window=window)

@@ -11,22 +11,24 @@ Depth is organized as ``lax.scan`` over repeating layer-pattern blocks
 (homogeneous stacks => small HLO, fast multi-arch dry-runs), with the
 remainder layers unrolled ("tail").  Layer kinds: global attention,
 local (sliding-window) attention, RG-LRU recurrence, RWKV6 time-mix.
-MoE configs replace every MLP with the top-k expert layer.
+MoE configs replace every MLP with the top-k expert layer.  Train and
+prefill run one layer body, ``_layer``; decode has its own mixers (cache
+update, single-token attention) and shares its FFN half, ``_ffn``.
+Attention itself picks its lowering per platform
+(:func:`repro.models.attention.chunked_attention`).
 
 Modality frontends (audio conv codec, ViT patch encoder) are stubs per
 the assignment: batches carry precomputed ``frames`` / ``patch_emb``.
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import GLOBAL, LOCAL, RGLRU, RWKV, ModelConfig
-from repro.models import attention, layers, moe, rglru, rwkv6
+from repro.models import attention, kvquant, layers, moe, rglru, rwkv6
 
 
 # ---------------------------------------------------------------------------
@@ -54,47 +56,67 @@ def _layer_init(key, kind: str, cfg: ModelConfig, dtype, cross=False):
     return p
 
 
-def _mixer_train(p, kind, x, positions, cfg, use_rope, pallas_fn):
-    """Sequence-mixer forward over a full sequence (train/prefill)."""
+def _layer(p, kind, x, positions, cfg, use_rope, enc_out=None, state=None):
+    """One pre-norm layer over a full sequence (train and prefill):
+    mixer -> [cross-attention] -> FFN.
+
+    Returns ``(x, aux, state, cross_kv)``: the MoE aux loss, the mixer's
+    state after ``x`` (``(k, v)`` for attention; for RG-LRU and RWKV the
+    recurrence's state, carried on from ``state``) and the
+    cross-attention's ``(k, v)`` over ``enc_out`` (None without it)."""
+    h = layers.rmsnorm(x, p["norm1"])
     if kind in (GLOBAL, LOCAL):
-        q, k, v = attention.project_qkv(p["attn"], x, cfg)
+        q, k, v = attention.project_qkv(p["attn"], h, cfg)
         if use_rope:
             q = layers.apply_rope(q, positions, cfg.rope_theta)
             k = layers.apply_rope(k, positions, cfg.rope_theta)
         window = cfg.window if kind == LOCAL else None
-        o = attention.chunked_attention(q, k, v, causal=True, window=window,
-                                        pallas_fn=pallas_fn)
-        B, S, _, _ = o.shape
-        y = o.reshape(B, S, -1) @ p["attn"]["wo"]
-        return y, (k, v)
-    if kind == RGLRU:
-        y, _ = rglru.rglru_apply(p["rglru"], x, cfg)
-        return y, None
-    if kind == RWKV:
-        y, _ = rwkv6.rwkv_apply(p["rwkv"], x, cfg,
-                                use_kernel=pallas_fn is not None)
-        return y, None
-    raise ValueError(kind)
-
-
-def _layer_train(p, kind, x, positions, cfg, use_rope, pallas_fn,
-                 enc_out=None):
-    """Full transformer layer (pre-norm): mixer -> [cross-attn] -> FFN."""
-    h = layers.rmsnorm(x, p["norm1"])
-    mix, _ = _mixer_train(p, kind, h, positions, cfg, use_rope, pallas_fn)
+        o = attention.chunked_attention(q, k, v, causal=True, window=window)
+        mix, state = o.reshape(*o.shape[:2], -1) @ p["attn"]["wo"], (k, v)
+    elif kind == RGLRU:
+        mix, state = rglru.rglru_apply(p["rglru"], h, cfg, state=state)
+    elif kind == RWKV:
+        mix, state = rwkv6.rwkv_apply(p["rwkv"], h, cfg, state=state)
+    else:
+        raise ValueError(kind)
     x = x + mix
+    cross_kv = None
     if enc_out is not None:
         h = layers.rmsnorm(x, p["norm_x"])
         q, _, _ = attention.project_qkv(p["xattn"], h, cfg)
         _, k, v = attention.project_qkv(p["xattn"], enc_out, cfg)
         o = attention.chunked_attention(q, k, v, causal=False)
         x = x + o.reshape(*o.shape[:2], -1) @ p["xattn"]["wo"]
+        cross_kv = (k, v)
+    x, aux = _ffn(p, x, cfg)
+    return x, aux, state, cross_kv
+
+
+def _ffn(p, x, cfg):
+    """A layer's second half: norm2, then MoE or MLP, then the residual.
+    Returns ``(x, aux)``."""
     h = layers.rmsnorm(x, p["norm2"])
     if "moe" in p:
         y, aux = moe.moe_apply(p["moe"], h, cfg)
     else:
         y, aux = layers.mlp_apply(p["mlp"], h, cfg.mlp), 0.0
     return x + y, aux
+
+
+def _fill_ring(cache_leaf, k, v, kv_quant):
+    """An attention layer's ring cache holding the trailing positions of
+    a prompt's ``(k, v)`` (int8 with scales under ``kv_quant``)."""
+    L = jax.tree.leaves(cache_leaf["k"])[0].shape[1]
+    S = k.shape[1]
+    take = min(L, S)
+    slots = jnp.mod(jnp.arange(S - take, S), L)
+
+    def put(leaf, val):
+        val = val[:, -take:]
+        if kv_quant:
+            val = dict(zip(("q", "scale"), kvquant.quantize_kv(val)))
+        return jax.tree.map(lambda c, n: c.at[:, slots].set(n), leaf, val)
+    return {"k": put(cache_leaf["k"], k), "v": put(cache_leaf["v"], v)}
 
 
 # ---------------------------------------------------------------------------
@@ -109,22 +131,14 @@ def _split_depth(cfg: ModelConfig):
 
 
 class Model:
-    def __init__(self, cfg: ModelConfig, use_pallas: bool = False,
-                 remat: bool = True, remat_policy=None,
+    def __init__(self, cfg: ModelConfig, remat: bool = True,
                  kv_quant: bool = False):
         self.cfg = cfg
         self.remat = remat
-        # int8 KV caches (repro.models.kvquant) — beyond-paper lever for
-        # memory-dominant decode shapes (EXPERIMENTS.md §Perf)
+        # int8 KV caches (repro.models.kvquant): about half the bytes of a
+        # bf16 cache, for decode shapes where reading the cache dominates
         self.kv_quant = kv_quant
-        # e.g. jax.checkpoint_policies.save_only_these_names("fsdp_gather")
-        # keeps FSDP param gathers out of the backward re-gather
-        self.remat_policy = remat_policy
         self.use_rope = not cfg.is_encoder_decoder
-        self.pallas_fn = None
-        if use_pallas:
-            from repro.kernels import ops as kops
-            self.pallas_fn = kops.swa_attention
         self.n_blocks, self.tail_kinds = _split_depth(cfg)
         # FSDP hook: fn(param_subtree, kind in {"block","tail"}, idx) ->
         # gathered subtree.  Set by the train-step builder (manual-mesh
@@ -197,19 +211,16 @@ class Model:
         x = frames.astype(jnp.dtype(cfg.dtype))
         x = x + layers.sinusoidal_positions(x.shape[1],
                                             cfg.d_model).astype(x.dtype)
-        positions = jnp.arange(x.shape[1])
 
         def body(x, p):
             h = layers.rmsnorm(x, p["norm1"])
             q, k, v = attention.project_qkv(p["attn"], h, cfg)
             o = attention.chunked_attention(q, k, v, causal=False)
             x = x + o.reshape(*o.shape[:2], -1) @ p["attn"]["wo"]
-            h = layers.rmsnorm(x, p["norm2"])
-            x = x + layers.mlp_apply(p["mlp"], h, cfg.mlp)
+            x, _ = _ffn(p, x, cfg)
             return x, None
 
-        fn = jax.checkpoint(body, policy=self.remat_policy) \
-            if self.remat else body
+        fn = jax.checkpoint(body) if self.remat else body
         x, _ = jax.lax.scan(lambda c, p: fn(c, p), x, params["encoder"])
         return layers.rmsnorm(x, params["enc_norm"])
 
@@ -224,8 +235,9 @@ class Model:
             enc_out = self._encode(params, batch["frames"])
 
         def layer(x, p, kind):
-            return _layer_train(p, kind, x, positions, cfg, self.use_rope,
-                                self.pallas_fn, enc_out=enc_out)
+            x, aux, _, _ = _layer(p, kind, x, positions, cfg, self.use_rope,
+                                  enc_out=enc_out)
+            return x, aux
 
         def block_body(carry, block_params):
             x, aux = carry
@@ -234,8 +246,7 @@ class Model:
                 aux = aux + a
             return (x, aux), None
 
-        fn = jax.checkpoint(block_body, policy=self.remat_policy) \
-            if self.remat else block_body
+        fn = jax.checkpoint(block_body) if self.remat else block_body
         carry = (x, jnp.zeros((), jnp.float32))
         if self.n_blocks > 0:
             carry, _ = jax.lax.scan(fn, carry, tuple(params["blocks"]))
@@ -264,7 +275,6 @@ class Model:
             if kind in (GLOBAL, LOCAL):
                 L = self._cache_len(kind, seq_len)
                 if self.kv_quant:
-                    from repro.models import kvquant
                     return {
                         "k": kvquant.init_quant_cache(
                             batch_size, L, cfg.n_kv_heads, cfg.head_dim),
@@ -324,62 +334,16 @@ class Model:
             enc_out = self._encode(params, batch["frames"])
 
         cache = self.init_cache(B, cache_len, swa_variant)
-        enc_layer_idx = [0]
 
         def layer(x, p, kind, cache_leaf):
-            new_cache = cache_leaf
-            h = layers.rmsnorm(x, p["norm1"])
+            x, _, state, cross_kv = _layer(p, kind, x, positions, cfg,
+                                           self.use_rope, enc_out=enc_out,
+                                           state=cache_leaf)
             if kind in (GLOBAL, LOCAL):
-                q, k, v = attention.project_qkv(p["attn"], h, cfg)
-                if self.use_rope:
-                    q = layers.apply_rope(q, positions, cfg.rope_theta)
-                    k = layers.apply_rope(k, positions, cfg.rope_theta)
-                window = cfg.window if kind == LOCAL else None
-                o = attention.chunked_attention(
-                    q, k, v, causal=True, window=window,
-                    pallas_fn=self.pallas_fn)
-                x = x + o.reshape(B, S, -1) @ p["attn"]["wo"]
-                # fill ring cache with the trailing L positions
-                if self.kv_quant:
-                    from repro.models import kvquant
-                    L = cache_leaf["k"]["q"].shape[1]
-                    take = min(L, S)
-                    slots = jnp.mod(jnp.arange(S - take, S), L)
-                    new_cache = {}
-                    for name, val in (("k", k), ("v", v)):
-                        qv, sv = kvquant.quantize_kv(val[:, -take:])
-                        new_cache[name] = {
-                            "q": cache_leaf[name]["q"].at[:, slots].set(qv),
-                            "scale": cache_leaf[name]["scale"]
-                            .at[:, slots].set(sv)}
-                else:
-                    L = cache_leaf["k"].shape[1]
-                    take = min(L, S)
-                    slots = jnp.mod(jnp.arange(S - take, S), L)
-                    new_cache = {
-                        "k": cache_leaf["k"].at[:, slots].set(k[:, -take:]),
-                        "v": cache_leaf["v"].at[:, slots].set(v[:, -take:])}
-            elif kind == RGLRU:
-                y, new_cache = rglru.rglru_apply(p["rglru"], h, cfg,
-                                                 state=cache_leaf)
-                x = x + y
-            elif kind == RWKV:
-                y, new_cache = rwkv6.rwkv_apply(p["rwkv"], h, cfg,
-                                                state=cache_leaf)
-                x = x + y
-            if enc_out is not None:
-                h = layers.rmsnorm(x, p["norm_x"])
-                q, _, _ = attention.project_qkv(p["xattn"], h, cfg)
-                _, ek, ev = attention.project_qkv(p["xattn"], enc_out, cfg)
-                o = attention.chunked_attention(q, ek, ev, causal=False)
-                x = x + o.reshape(B, S, -1) @ p["xattn"]["wo"]
-                new_cache = (new_cache, {"k": ek, "v": ev})
-            h = layers.rmsnorm(x, p["norm2"])
-            if "moe" in p:
-                y, _ = moe.moe_apply(p["moe"], h, cfg)
-            else:
-                y = layers.mlp_apply(p["mlp"], h, cfg.mlp)
-            return x + y, new_cache
+                state = _fill_ring(cache_leaf, *state, self.kv_quant)
+            if cross_kv is not None:
+                state = (state, {"k": cross_kv[0], "v": cross_kv[1]})
+            return x, state
 
         def block_body(x, xs):
             block_params, block_cache = xs
@@ -389,8 +353,7 @@ class Model:
                 new_cache.append(nc)
             return x, tuple(new_cache)
 
-        fn = jax.checkpoint(block_body, policy=self.remat_policy) \
-            if self.remat else block_body
+        fn = jax.checkpoint(block_body) if self.remat else block_body
         enc_caches = []
         if self.n_blocks > 0:
             x, new_blocks = jax.lax.scan(
@@ -439,7 +402,6 @@ class Model:
                     k = layers.apply_rope(k, positions, cfg.rope_theta)
                 window = cfg.window if kind == LOCAL else None
                 if self.kv_quant:
-                    from repro.models import kvquant
                     kc = kvquant.quant_cache_update(cache_leaf["k"], k, pos)
                     vc = kvquant.quant_cache_update(cache_leaf["v"], v, pos)
                     o = attention.decode_attention_quant(q, kc, vc, pos,
@@ -465,12 +427,8 @@ class Model:
                 o = attention.decode_attention(q, enc_kv["k"], enc_kv["v"],
                                                enc_kv["k"].shape[1] - 1)
                 x = x + o.reshape(B, 1, -1) @ p["xattn"]["wo"]
-            h = layers.rmsnorm(x, p["norm2"])
-            if "moe" in p:
-                y, _ = moe.moe_apply(p["moe"], h, cfg)
-            else:
-                y = layers.mlp_apply(p["mlp"], h, cfg.mlp)
-            return x + y, new_cache
+            x, _ = _ffn(p, x, cfg)
+            return x, new_cache
 
         P = len(pattern)
         enc_kv = cache.get("enc_kv")

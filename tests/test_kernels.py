@@ -11,9 +11,9 @@ RS = np.random.RandomState(0)
 
 
 # ---------------------------------------------------------------------------
-# sliding-window flash attention
+# causal (optionally windowed) flash attention
 # ---------------------------------------------------------------------------
-SWA_CASES = [
+FLASH_CASES = [
     # (B, S, H, KV, hd, window, dtype)
     (2, 256, 4, 2, 64, None, jnp.float32),
     (1, 512, 8, 8, 128, 128, jnp.float32),
@@ -24,25 +24,25 @@ SWA_CASES = [
 ]
 
 
-@pytest.mark.parametrize("B,S,H,KV,hd,window,dtype", SWA_CASES)
-def test_swa_kernel_vs_ref(B, S, H, KV, hd, window, dtype):
+@pytest.mark.parametrize("B,S,H,KV,hd,window,dtype", FLASH_CASES)
+def test_flash_kernel_vs_ref(B, S, H, KV, hd, window, dtype):
     q = jnp.asarray(RS.randn(B, S, H, hd), dtype)
     k = jnp.asarray(RS.randn(B, S, KV, hd), dtype)
     v = jnp.asarray(RS.randn(B, S, KV, hd), dtype)
-    out = ops.swa_attention(q, k, v, window=window)
-    expect = ref.swa_attention(q, k, v, window=window)
+    out = ops.flash_attention(q, k, v, window=window)
+    expect = ref.flash_attention(q, k, v, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), atol=tol)
 
 
-def test_swa_kernel_grad():
+def test_flash_kernel_grad():
     B, S, H, KV, hd = 1, 256, 4, 2, 64
     q = jnp.asarray(RS.randn(B, S, H, hd), jnp.float32)
     k = jnp.asarray(RS.randn(B, S, KV, hd), jnp.float32)
     v = jnp.asarray(RS.randn(B, S, KV, hd), jnp.float32)
-    f1 = lambda *a: jnp.sum(jnp.tanh(ops.swa_attention(*a, window=64)))
-    f2 = lambda *a: jnp.sum(jnp.tanh(ref.swa_attention(*a, window=64)))
+    f1 = lambda *a: jnp.sum(jnp.tanh(ops.flash_attention(*a, window=64)))
+    f2 = lambda *a: jnp.sum(jnp.tanh(ref.flash_attention(*a, window=64)))
     g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):

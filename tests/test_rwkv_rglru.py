@@ -70,27 +70,24 @@ def test_rglru_scan_equals_stepwise():
 
 
 def test_rwkv_pallas_kernel_path_matches_jnp():
-    """use_pallas=True routes WKV through the Pallas kernel with a
+    """use_kernel=True routes WKV through the Pallas kernel with a
     custom-VJP backward — forward and grads must match the jnp path."""
-    from repro.models import build_model
     cfg = _cfg("rwkv6-7b")
-    m1 = build_model(cfg, remat=False)
-    m2 = build_model(cfg, remat=False, use_pallas=True)
-    params = m1.init(jax.random.PRNGKey(0))
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 64),
-                                          0, cfg.vocab_size)}
-    y1, _ = jax.jit(m1.apply)(params, batch)
-    y2, _ = jax.jit(m2.apply)(params, batch)
+    p = rwkv6.rwkv_init(jax.random.PRNGKey(0), cfg, jnp.float32)
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (2, 64, cfg.d_model))
+
+    def run(use_kernel):
+        return lambda p_, x_: rwkv6.rwkv_apply(p_, x_, cfg,
+                                               use_kernel=use_kernel)[0]
+    y1 = jax.jit(run(False))(p, x)
+    y2 = jax.jit(run(True))(p, x)
     np.testing.assert_allclose(np.asarray(y1, np.float32),
                                np.asarray(y2, np.float32), atol=1e-4)
 
-    def loss(m):
-        def f(p):
-            logits, _ = m.apply(p, batch)
-            return jnp.mean(logits.astype(jnp.float32) ** 2)
-        return f
-    g1 = jax.grad(loss(m1))(params)
-    g2 = jax.grad(loss(m2))(params)
+    def loss(use_kernel):
+        return lambda p_, x_: jnp.mean(run(use_kernel)(p_, x_) ** 2)
+    g1 = jax.grad(loss(False), argnums=(0, 1))(p, x)
+    g2 = jax.grad(loss(True), argnums=(0, 1))(p, x)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), atol=1e-4)

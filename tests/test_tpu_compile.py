@@ -25,7 +25,6 @@ from repro.kernels import block_significance as bs
 from repro.kernels import flash_attention as kflash
 from repro.kernels import fused_adamw as fa
 from repro.kernels import robust_agg
-from repro.kernels import swa_attention as swa
 from repro.models import build_model
 
 CFG = get_config("smollm-135m")
@@ -186,7 +185,7 @@ def test_train_step_kernels_are_named_in_their_phase(train_step_text,
     "block_significance_norms", "block_significance_filter", "fused_adamw",
     "robust_agg_trimmed_mean", "robust_agg_median", "robust_agg_krum_sqdist",
     "robust_agg_weiszfeld_sqdist", "robust_agg_weiszfeld_wsum",
-    "swa_attention", "flash_attention_fwd", "flash_attention_bwd"])
+    "flash_attention_fwd", "flash_attention_bwd"])
 def test_kernel_instruction_carries_its_name(one_chip, kernel):
     W, D, n = 4, 8192, 4096
     f32 = jnp.float32
@@ -215,17 +214,13 @@ def test_kernel_instruction_carries_its_name(one_chip, kernel):
             lambda x, z: robust_agg.weiszfeld_step(x, z, 1e-12,
                                                    interpret=False),
             ((W, D), f32), ((D,), f32)),
-        "swa_attention": (
-            lambda q, k, v: swa.swa_attention_fwd(q, k, v, interpret=False),
+        "flash_attention_fwd": (
+            lambda q, k, v: kflash.flash_attention(q, k, v, interpret=False),
             ((1, 512, CFG.n_heads, CFG.head_dim), jnp.bfloat16),
             ((1, 512, CFG.n_kv_heads, CFG.head_dim), jnp.bfloat16),
             ((1, 512, CFG.n_kv_heads, CFG.head_dim), jnp.bfloat16)),
     }
     calls["robust_agg_weiszfeld_wsum"] = calls["robust_agg_weiszfeld_sqdist"]
-    heads = calls["swa_attention"][1:]
-    calls["flash_attention_fwd"] = (
-        lambda q, k, v: kflash.flash_attention(q, k, v, interpret=False),
-        *heads)
     # the backward called as such: under jax.grad the instruction's name
     # takes the transforms' prefix (``transpose_jvp_...``)
     group = (1, CFG.n_kv_heads, CFG.n_heads // CFG.n_kv_heads, 512)
@@ -242,15 +237,24 @@ def test_kernel_instruction_carries_its_name(one_chip, kernel):
 
 
 @pytest.mark.parametrize("window", [None, 1024])
-def test_swa_attention_compiles(one_chip, window):
+def test_flash_attention_compiles(one_chip, window):
+    """The forward and, through ``jax.vjp``, the fused backward compile
+    for v5e at S 2048 with smollm's heads, windowed or not."""
     B, S = 1, 2048
     q = ((B, S, CFG.n_heads, CFG.head_dim), jnp.bfloat16)
     kv = ((B, S, CFG.n_kv_heads, CFG.head_dim), jnp.bfloat16)
-    txt = _compiled_text(
-        lambda q_, k_, v_: swa.swa_attention_fwd(
-            q_, k_, v_, window=window, interpret=False),
-        q, kv, kv, sharding=one_chip)
-    assert "tpu_custom_call" in txt
+
+    def fwd_bwd(q_, k_, v_):
+        out, vjp = jax.vjp(
+            lambda *a: kflash.flash_attention(*a, window=window,
+                                              interpret=False), q_, k_, v_)
+        return out, vjp(out)
+    txt = _compiled_text(fwd_bwd, q, kv, kv, sharding=one_chip)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
+        # under jax.vjp the names take the transforms' affixes
+        # (``jvp_flash_attention_fwd_``, ``transpose_jvp_..._bwd__``)
+        assert re.search(rf"%\w*{kernel}\w*(\.\d+)? = .*tpu_custom_call",
+                         txt), kernel
 
 
 @pytest.fixture(scope="module")
