@@ -39,6 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -284,6 +285,10 @@ def _attend(q, k, v, window, block, interpret):
 
 def _attend_fwd(q, k, v, window, block, interpret):
     o, lse = _forward(q, k, v, window, block, block, interpret)
+    # named for remat's policy (``models/transformer._REMAT_POLICY``), which
+    # keeps them so the backward does not run this forward again
+    o = checkpoint_name(o, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
     return o, (q, k, v, o, lse)
 
 

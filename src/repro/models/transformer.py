@@ -31,6 +31,16 @@ from repro.configs.base import GLOBAL, LOCAL, RGLRU, RWKV, ModelConfig
 from repro.models import attention, kvquant, layers, moe, rglru, rwkv6
 
 
+# What remat keeps of a layer scan besides its carry: the output and
+# log-sum-exp of the TPU flash-attention kernel, which its custom VJP
+# names for its backward (``flash_attention._attend_fwd``).  Without them
+# the backward would run the kernel's forward again.  A layer with no
+# kernel attention (RG-LRU, RWKV, the XLA ``_flash`` path) names nothing
+# and keeps only the carry.
+_REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    "attn_out", "attn_lse")
+
+
 # ---------------------------------------------------------------------------
 # per-layer init / apply
 # ---------------------------------------------------------------------------
@@ -220,7 +230,8 @@ class Model:
             x, _ = _ffn(p, x, cfg)
             return x, None
 
-        fn = jax.checkpoint(body) if self.remat else body
+        fn = jax.checkpoint(body, policy=_REMAT_POLICY) if self.remat \
+            else body
         x, _ = jax.lax.scan(lambda c, p: fn(c, p), x, params["encoder"])
         return layers.rmsnorm(x, params["enc_norm"])
 
@@ -246,7 +257,8 @@ class Model:
                 aux = aux + a
             return (x, aux), None
 
-        fn = jax.checkpoint(block_body) if self.remat else block_body
+        fn = jax.checkpoint(block_body, policy=_REMAT_POLICY) \
+            if self.remat else block_body
         carry = (x, jnp.zeros((), jnp.float32))
         if self.n_blocks > 0:
             carry, _ = jax.lax.scan(fn, carry, tuple(params["blocks"]))

@@ -14,9 +14,9 @@ import pytest
 
 from repro import optim
 from repro.configs.base import get_config
-from repro.core import build_train_step, get_strategy
+from repro.core import build_train_step, get_strategy, losses
 from repro.core.sharding import make_mesh
-from repro.models import build_cnn, build_model
+from repro.models import attention, build_cnn, build_model
 
 ARCHS = [
     "mixtral-8x22b", "gemma3-4b", "mixtral-8x7b", "rwkv6-7b", "pixtral-12b",
@@ -70,6 +70,47 @@ def test_one_train_step(arch):
     after = jax.tree.leaves(new_state["params"])[0]
     assert not np.allclose(np.asarray(before, np.float32),
                            np.asarray(after, np.float32))
+
+
+@pytest.mark.parametrize("arch,n_layers", [
+    ("smollm-135m", 2), ("recurrentgemma-2b", 3), ("whisper-small", 2)])
+def test_remat_keeps_loss_and_grads(arch, n_layers, monkeypatch):
+    """Remat's policy keeps the flash kernel's output and log-sum-exp: the
+    loss and every gradient equal those without remat at f32.  Causal
+    self-attention is steered onto the kernel (interpret mode here).
+    Three layers give recurrentgemma one RG-LRU, RG-LRU, local-attention
+    block; whisper adds the encoder's scan and cross-attention."""
+    steered = []
+
+    def uses_flash_kernel(q, k, causal):
+        steered.append(causal and k.shape[1] == q.shape[1])
+        return steered[-1]
+    monkeypatch.setattr(attention, "uses_flash_kernel", uses_flash_kernel)
+    cfg = get_config(arch).reduced(n_layers=n_layers)
+    batch = _batch(cfg, S=128)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+
+    def value_and_grad(remat):
+        model = build_model(cfg, remat=remat)
+
+        def loss(p):
+            logits, aux = model.apply(p, batch)
+            return losses.softmax_cross_entropy(logits, batch["labels"]) + aux
+        return jax.jit(jax.value_and_grad(loss))(params)
+
+    (got, g_got), (want, g_want) = value_and_grad(True), value_and_grad(False)
+    assert any(steered)
+    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
+    # each leaf on the larger of its own norm and the median leaf's, as
+    # the chip benchmark's check: some leaves' gradients are 0 but for
+    # rounding (a key bias under softmax)
+    g_got, g_want = ({jax.tree_util.keystr(k): np.asarray(x, np.float64)
+                      for k, x in jax.tree_util.tree_leaves_with_path(g)}
+                     for g in (g_got, g_want))
+    median = np.median([np.linalg.norm(x) for x in g_want.values()])
+    for name, b in g_want.items():
+        err = np.linalg.norm(g_got[name] - b)
+        assert err <= 1e-5 * max(np.linalg.norm(b), median), name
 
 
 @pytest.mark.parametrize("kind", ["mobilenet-cifar", "resnet18-cifar"])

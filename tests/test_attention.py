@@ -2,11 +2,14 @@
 dense float32 reference, and a lowering check that the differentiated
 model never stacks mask or fill arrays of the full score-block shape.
 The Pallas flash kernels (``kernels/flash_attention.py``, interpret mode
-here) against both, and the rule that picks them."""
+here) against both, and the rule that picks them.  Remat over the layer
+scan keeps the kernel's output and log-sum-exp, so the gradient runs no
+kernel forward again."""
 import dataclasses
 import re
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -218,3 +221,42 @@ def test_chunked_attention_runs_the_kernels_where_dispatch_says(
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
     for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
         assert (kernel in text) is causal, kernel
+
+
+def _eqns(jaxpr, in_remat=False):
+    """Each equation of ``jaxpr`` and of the jaxprs inside it, with
+    whether it lies in a ``remat2`` region (remat's recompute, and the
+    backward that the transpose puts beside it)."""
+    for e in jaxpr.eqns:
+        yield e, in_remat
+        inner = in_remat or e.primitive.name == "remat2"
+        for v in e.params.values():
+            for x in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(x, jax.extend.core.ClosedJaxpr):
+                    x = x.jaxpr
+                if isinstance(x, jax.extend.core.Jaxpr):
+                    yield from _eqns(x, inner)
+
+
+def test_remat_reruns_no_flash_kernel_forward(monkeypatch):
+    """On TPU (steered) the loss gradient of a two-layer smollm-135m (its
+    widths and head layout, remat over the layer scan, batch 2 x 1024)
+    runs ``flash_attention_fwd`` once, in the forward, and never in the
+    remat region."""
+    monkeypatch.setattr(kops, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("smollm-135m"), n_layers=2,
+                              vocab_size=512)
+    model = build_model(cfg, remat=True)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 1024), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((2, 1024), jnp.int32)}
+
+    def loss(p, b):
+        logits, aux = model.apply(p, b)
+        return losses.softmax_cross_entropy(logits, b["labels"]) + aux
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params, batch).jaxpr
+    where = [r for e, r in _eqns(jaxpr)
+             if e.primitive.name == "pallas_call"
+             and e.params["name"] == "flash_attention_fwd"]
+    assert where == [False]
