@@ -28,7 +28,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import checks, flops, hlo, spec, trace, traffic
+from chipbench import checks, hlo, reference, spec, trace, traffic
 from chipbench.spec import Cell
 
 #: seconds of steps the traced slice holds (at least ``TRACE_MIN_STEPS``)
@@ -151,7 +151,7 @@ def reference_readings(cell: Cell, shapes, seed: int, device, pool,
     """The plain reference (or, given ``num``, a control) trained from the
     same weights on the same first rows; ``against`` is the first gradient
     of the run it is compared with."""
-    from chipbench import reference, system
+    from chipbench import system
     from chipbench.reference.numerics import REFERENCE
     params = system.make_params(shapes, cell.config, seed, device)
     return reference.train_readings(cell.config, cell.traffic, params,
@@ -184,9 +184,10 @@ def run(cell: Cell, seed: int, seconds: float, do_trace: bool, devices,
     in_window = len(compiles) - n_before
     mem = peak_bytes(used)
 
+    arch = reference.module_for(cell.config)
     run_rec = Run(cell=cell, work_per_step=traffic.work_per_step(cell.traffic),
-                  flops_per_unit=flops.train_flops_per_unit(
-                      cell.config, cell.traffic),
+                  flops_per_unit=arch.train_flops_per_unit(cell.config,
+                                                           cell.traffic),
                   peak=peak, setup_s=setup_s, step_s=step_s,
                   window_s=window_s)
 

@@ -1,6 +1,7 @@
 """Whole train step's share of the chips' bf16 peak, LM cells: tokens per
 second of the window x model FLOPs per token (three forward passes, from
-``chipbench.flops``; recomputation not counted), over chips x peak."""
+the configuration's reference module; recomputation not counted), over
+chips x peak."""
 UNIT = "%"
 LAYER = "train step"
 

@@ -14,9 +14,16 @@ and the head is its own matrix (``tie_word_embeddings`` false).
 The parameter tree is laid out as the weights are made: ``embed/table``
 (V, d), ``unembed/table`` (d, V), ``final_norm`` (d,), and one stacked
 block whose leaves carry the layer index first.
+
+Besides the reference, the module binds a configuration of this family to
+the benchmark: ``program_config`` lays the file's sizes onto the program's
+config, ``init_leaf`` makes each weight, and ``train_flops_per_unit``
+counts a training step's model FLOPs.  Only ``program_config`` reads the
+program; the reference never calls it.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict
 
 import jax
@@ -104,3 +111,54 @@ def block_loss_sum(params, block, config, num):
 def units(batch) -> int:
     """Tokens the loss is averaged over."""
     return int(batch["labels"].size)
+
+
+def program_config(config: Dict[str, Any]):
+    """The program's config object, its sizes taken from the file."""
+    from repro.configs.base import get_config
+    c = config["config"]
+    return dataclasses.replace(
+        get_config(config["program"]), n_layers=c["num_hidden_layers"],
+        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"],
+        head_dim=c["hidden_size"] // c["num_attention_heads"],
+        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
+        rope_theta=c["rope_theta"], dtype=config["precision"]["params"])
+
+
+def init_leaf(key, name, shape, config):
+    """Scales of the program's own initializer; norm gains get a spread so
+    that a path that drops them is seen."""
+    last = name.split("/")[-1]
+    if last == "table":
+        std = config["config"]["initializer_range"]
+        return jax.random.normal(key, shape, jnp.float32) * std
+    if last.startswith("norm") or last == "final_norm":
+        return jax.random.normal(key, shape, jnp.float32) * 0.1
+    fan_in = shape[-2]
+    return jax.random.normal(key, shape, jnp.float32) / fan_in ** 0.5
+
+
+def forward_flops(c: Dict[str, Any], batch: int, seq_len: int) -> float:
+    """Model FLOPs of a forward pass (1 MAC = 2 FLOPs): the benchmark's
+    copy of ``repro.costmodel.flops.forward_flops`` for a dense model of
+    global attention layers.  Every matmul, attention scores counted
+    causally (each query against half the sequence on average), the
+    embedding lookup not counted."""
+    d, f, V = c["hidden_size"], c["intermediate_size"], c["vocab_size"]
+    H, KV = c["num_attention_heads"], c["num_key_value_heads"]
+    hd = d // H
+    tokens = batch * seq_len
+    proj = 2 * tokens * d * (H + 2 * KV) * hd + 2 * tokens * H * hd * d
+    scores = 2 * tokens * (seq_len / 2) * H * hd * 2      # QK^T and PV
+    mlp = 2 * tokens * 3 * d * f                           # SwiGLU
+    head = 2 * tokens * d * V
+    return c["num_hidden_layers"] * (proj + scores + mlp) + head
+
+
+def train_flops_per_unit(config: Dict[str, Any],
+                         traffic: Dict[str, Any]) -> float:
+    """Model FLOPs of one training step per token: three forward passes
+    (forward, and backward at twice that); recomputation not counted."""
+    S = traffic["seq_len"]
+    return 3.0 * forward_flops(config["config"], 1, S) / S

@@ -8,9 +8,16 @@ pool, linear head, mean cross-entropy.
 Departures from the published model, as the configuration runs it:
 GroupNorm of ``groups`` groups in place of BatchNorm, and "SAME" padding,
 which for a stride-2 3x3 convolution pads only the bottom and right.
+
+Besides the reference, the module binds a configuration of this family to
+the benchmark: ``program_config`` lays the file's sizes onto the program's
+config, ``init_leaf`` makes each weight, and ``train_flops_per_unit``
+counts a training step's model FLOPs.  Only ``program_config`` reads the
+program; the reference never calls it.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict
 
 import jax
@@ -72,3 +79,58 @@ def row_blocks(batch):
 
 def units(batch) -> int:
     return int(batch["labels"].size)
+
+
+def program_config(config: Dict[str, Any]):
+    """The program's config object, its sizes taken from the file."""
+    from repro.configs.base import get_config
+    c = config["config"]
+    return dataclasses.replace(
+        get_config(config["program"]), num_classes=c["num_classes"],
+        image_size=c["image_size"], channels=c["channels"],
+        width_mult=c["width_mult"], dtype=config["precision"]["params"])
+
+
+def init_leaf(key, name, shape, config):
+    parts = name.split("/")
+    last = parts[-1]
+    if last == "scale":
+        return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
+    if last == "bias" or (parts[0] == "head" and last == "b"):
+        return 0.1 * jax.random.normal(key, shape, jnp.float32)
+    if parts[0] == "head":
+        return jax.random.normal(key, shape, jnp.float32) / shape[0] ** 0.5
+    fan_in = 1
+    for d in shape[:-1]:
+        fan_in *= d
+    return jax.random.normal(key, shape, jnp.float32) * (2.0 / fan_in) ** 0.5
+
+
+def forward_flops(c: Dict[str, Any], batch: int) -> float:
+    """Model FLOPs of a forward pass (1 MAC = 2 FLOPs): every convolution
+    and the head, from the stage list."""
+    size, cin = c["image_size"], c["channels"]
+    total = 0.0
+
+    def conv(hw, k, ci, co):
+        return 2.0 * hw * hw * k * k * ci * co
+    width = c["stem_width"]
+    total += conv(size, 3, cin, width)
+    hw, cin = size, width
+    for cout, stride in c["stages"]:
+        for b in range(c["blocks_per_stage"]):
+            s = stride if b == 0 else 1
+            out_hw = -(-hw // s)
+            total += conv(out_hw, 3, cin, cout) + conv(out_hw, 3, cout, cout)
+            if s != 1 or cin != cout:
+                total += conv(out_hw, 1, cin, cout)
+            hw, cin = out_hw, cout
+    total += 2.0 * cin * c["num_classes"]
+    return batch * total
+
+
+def train_flops_per_unit(config: Dict[str, Any],
+                         traffic: Dict[str, Any]) -> float:
+    """Model FLOPs of one training step per image: three forward passes
+    (forward, and backward at twice that)."""
+    return 3.0 * forward_flops(config["config"], 1)

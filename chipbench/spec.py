@@ -4,7 +4,10 @@ Everything that belongs to one configuration, one traffic mix, one cell or
 one metric sits in a file of its own, found by the name ``BENCHMARK.json``
 gives it:
 
-  configs/<config>.json     sizes, source, what was changed, precision
+  configs/<config>.json     sizes, source, what was changed, precision, and
+                            the architecture's module it names
+  reference/<module>.py     an architecture: reference, program sizes,
+                            weights, FLOPs (``reference.module_for``)
   traffic/<traffic>.json    the job: batch, lengths, pool, strategy, optimizer
   workloads/<cell>.json     config + traffic + chips + mesh + why + limits
   metrics/<metric>.py       a reader: UNIT, LAYER and read(run) -> number|None

@@ -7,37 +7,27 @@ drives the step's ``step_fn``.  ``make_params`` is the benchmark's own
 weight generator: one jitted call from the seed, on the device, in the
 dtypes the program holds its parameters in.  The plain reference starts
 from the same generator, so it takes nothing that the program has made.
+The program's sizes and each weight's scale come from the configuration's
+reference module (``chipbench.reference.module_for``).
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
+from pathlib import Path
 from typing import Any, Dict
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from chipbench.spec import Cell
+from chipbench import reference
+from chipbench.spec import HERE, Cell
 
 
-def program_config(config: Dict[str, Any]):
-    """The program's config object, its sizes taken from the file."""
-    from repro.configs.base import get_config
-    c = config["config"]
-    base = get_config(config["program"])
-    if config["kind"] == "lm":
-        return dataclasses.replace(
-            base, n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-            n_heads=c["num_attention_heads"],
-            n_kv_heads=c["num_key_value_heads"],
-            head_dim=c["hidden_size"] // c["num_attention_heads"],
-            d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-            rope_theta=c["rope_theta"], dtype=config["precision"]["params"])
-    return dataclasses.replace(
-        base, num_classes=c["num_classes"], image_size=c["image_size"],
-        channels=c["channels"], width_mult=c["width_mult"],
-        dtype=config["precision"]["params"])
+def program_config(config: Dict[str, Any], base: Path = HERE):
+    """The program's config object, its sizes taken from the file by the
+    configuration's reference module."""
+    return reference.module_for(config, base).program_config(config)
 
 
 @dataclasses.dataclass
@@ -98,44 +88,18 @@ def _leaf_key(seed_key, name: str):
                               & 0x7FFFFFFF)
 
 
-def _lm_leaf(key, name, shape, config):
-    """Scales of the program's own initializer; norm gains get a spread so
-    that a path that drops them is seen."""
-    last = name.split("/")[-1]
-    if last == "table":
-        std = config["config"]["initializer_range"]
-        return jax.random.normal(key, shape, jnp.float32) * std
-    if last.startswith("norm") or last == "final_norm":
-        return jax.random.normal(key, shape, jnp.float32) * 0.1
-    fan_in = shape[-2]
-    return jax.random.normal(key, shape, jnp.float32) / fan_in ** 0.5
-
-
-def _cnn_leaf(key, name, shape, config):
-    parts = name.split("/")
-    last = parts[-1]
-    if last == "scale":
-        return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
-    if last == "bias" or (parts[0] == "head" and last == "b"):
-        return 0.1 * jax.random.normal(key, shape, jnp.float32)
-    if parts[0] == "head":
-        return jax.random.normal(key, shape, jnp.float32) / shape[0] ** 0.5
-    fan_in = 1
-    for d in shape[:-1]:
-        fan_in *= d
-    return jax.random.normal(key, shape, jnp.float32) * (2.0 / fan_in) ** 0.5
-
-
 def param_shapes(model):
     return jax.eval_shape(model.init, jax.random.PRNGKey(0))
 
 
-def make_params(shapes, config: Dict[str, Any], seed: int, device):
+def make_params(shapes, config: Dict[str, Any], seed: int, device,
+                base: Path = HERE):
     """Every parameter from ``seed`` in one jitted call on ``device``, in
-    the dtype of ``shapes`` (the program's parameter tree)."""
+    the dtype of ``shapes`` (the program's parameter tree), each leaf made
+    by the reference module's ``init_leaf``."""
     from chipbench.traffic import rng_for
     seed32 = int(rng_for(seed, "weights").integers(0, 2 ** 31 - 1))
-    leaf = _lm_leaf if config["kind"] == "lm" else _cnn_leaf
+    leaf = reference.module_for(config, base).init_leaf
 
     def gen(key):
         def one(path, s):

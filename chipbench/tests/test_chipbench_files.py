@@ -1,6 +1,7 @@
 """The benchmark's files: BENCHMARK.json keeps its contract, every cell,
 configuration and metric it names loads by name, and a new one is found
 as a file with no edit anywhere."""
+import dataclasses
 import json
 import re
 import shutil
@@ -12,7 +13,8 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from chipbench import spec  # noqa: E402
+from chipbench import reference, spec, system, traffic  # noqa: E402
+from chipbench.reference.numerics import REFERENCE  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -93,26 +95,94 @@ def test_peaks_table_keyed_by_device_kind():
         spec.peaks("cpu")
 
 
+#: a new architecture's module: the LM's, with its loss, weights and FLOPs
+#: doubled and its own program name, so that each use shows which ran
+TINY_ARCH = '''
+import dataclasses
+
+from chipbench.reference import lm
+
+row_blocks, units = lm.row_blocks, lm.units
+
+
+def block_loss_sum(params, block, config, num):
+    return 2.0 * lm.block_loss_sum(params, block, config, num)
+
+
+def program_config(config):
+    return dataclasses.replace(lm.program_config(config), name="tiny-arch")
+
+
+def init_leaf(key, name, shape, config):
+    return 2.0 * lm.init_leaf(key, name, shape, config)
+
+
+def train_flops_per_unit(config, traffic):
+    return 2.0 * lm.train_flops_per_unit(config, traffic)
+'''
+
+
 def test_new_cell_config_traffic_and_metric_are_found_as_files(tmp_path):
-    """A later PR adds files; nothing that exists changes."""
+    """A later PR adds files; nothing that exists changes.  A new
+    architecture is a configuration naming a new reference module, which
+    the program's sizes, the weights, the FLOP count and the reference's
+    training all go through."""
+    import jax
+    from repro.models import build_model
     base = tmp_path / "bench"
     shutil.copytree(ROOT / "chipbench" / "tests" / "data", base)
+    before = {p: p.read_bytes() for p in base.rglob("*") if p.is_file()}
     (base / "metrics").mkdir()
+    (base / "reference").mkdir()
+    (base / "reference" / "tiny_arch.py").write_text(TINY_ARCH)
+    c = json.loads((base / "configs" / "tiny-lm.json").read_text())
+    c.update(name="tiny-arch", reference="tiny_arch")
+    (base / "configs" / "tiny-arch.json").write_text(json.dumps(c))
     w = json.loads((base / "workloads" / "tiny-lm-1.json").read_text())
-    w["name"] = "tiny-lm-new"
+    w.update(name="tiny-lm-new", config="tiny-arch")
     (base / "workloads" / "tiny-lm-new.json").write_text(json.dumps(w))
     (base / "metrics" / "steps.count.py").write_text(
         'UNIT = "steps"\nLAYER = "harness loop"\n\n\n'
         'def read(run):\n    return len(run.step_s)\n')
     cell = spec.load_cell("tiny-lm-new", base)
     assert cell.traffic["kind"] == "lm" and cell.chips == 1
+    assert cell.config["reference"] == "tiny_arch"
     reader = spec.load_reader("steps.count", base)
     assert reader.UNIT == "steps"
     bench = {"end_to_end": [{"name": "tokens_per_s"}],
              "per_layer": [{"name": "steps.count", "moves": "tokens_per_s"}]}
     found = spec.metrics_for(bench, "tiny-lm-new", "per_layer")
     assert [m["name"] for m in found] == ["steps.count"]
+
+    arch = reference.module_for(cell.config, base)
+    assert Path(arch.__file__) == base / "reference" / "tiny_arch.py"
+    lm_cell = spec.load_cell("tiny-lm-1", base)
+    assert lm_cell.config["reference"] == "lm"
+    lm_flops = reference.module_for(lm_cell.config).train_flops_per_unit(
+        lm_cell.config, lm_cell.traffic)
+    assert arch.train_flops_per_unit(cell.config, cell.traffic) == \
+        2 * lm_flops
+    cfg = system.program_config(cell.config, base)
+    lm_cfg = system.program_config(lm_cell.config)
+    assert cfg.name == "tiny-arch"
+    assert dataclasses.replace(cfg, name=lm_cfg.name) == lm_cfg
+    shapes = system.param_shapes(build_model(cfg))
+    dev = jax.devices()[0]
+    params = system.make_params(shapes, cell.config, 7, dev, base)
+    lm_params = system.make_params(shapes, lm_cell.config, 7, dev)
+    assert all(jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool((a == 2 * b).all()), params, lm_params)))
+    pool = traffic.make_pool(cell.traffic, cell.config, 7)
+    ref = reference.train_readings(cell.config, cell.traffic, lm_params,
+                                   pool[:1], REFERENCE, base=base)
+    lm_ref = reference.train_readings(lm_cell.config, lm_cell.traffic,
+                                      lm_params, pool[:1], REFERENCE)
+    assert ref["losses"][0] == pytest.approx(2 * lm_ref["losses"][0],
+                                             rel=1e-6)
+
     with pytest.raises(ValueError):
         w["name"] = "other"
         (base / "workloads" / "misnamed.json").write_text(json.dumps(w))
         spec.load_cell("misnamed", base)
+    assert {p: p.read_bytes() for p in before} == before
+    assert not (ROOT / "chipbench" / "reference" / "tiny_arch.py").exists()

@@ -1,5 +1,6 @@
-"""FLOP counters pinned against hand counts, and the HLO reader's matmul
-FLOPs against the shapes of a small compiled program."""
+"""FLOP counters of the reference modules pinned against hand counts,
+and the HLO reader's matmul FLOPs against the shapes of a small compiled
+program."""
 import sys
 from pathlib import Path
 
@@ -8,7 +9,8 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from chipbench import flops, hlo, spec  # noqa: E402
+from chipbench import hlo, reference, spec  # noqa: E402
+from chipbench.reference import lm, resnet  # noqa: E402
 
 
 def test_smollm_135m_forward_flops_per_token():
@@ -19,14 +21,15 @@ def test_smollm_135m_forward_flops_per_token():
     per_layer = 1_769_472 + 2_359_296 + 5_308_416
     per_token = 30 * per_layer + 56_623_104
     assert per_token == 339_738_624
-    assert flops.lm_forward_flops(c.config["config"], 1, 2048) == \
+    assert lm.forward_flops(c.config["config"], 1, 2048) == \
         pytest.approx(per_token * 2048, rel=1e-12)
-    assert flops.train_flops_per_unit(c.config, c.traffic) == \
+    arch = reference.module_for(c.config)
+    assert arch.train_flops_per_unit(c.config, c.traffic) == \
         pytest.approx(3 * per_token, rel=1e-12)
 
 
 def test_resnet18_cifar_forward_flops_per_image():
-    c = spec.load_cell("resnet18cifar-train-1chip")
+    c = spec.load_cell("resnet18cifar-train-b512-1chip")
     stem = 2 * 32 * 32 * 9 * 3 * 64
     stage1 = 4 * 2 * 32 * 32 * 9 * 64 * 64
 
@@ -37,8 +40,9 @@ def test_resnet18_cifar_forward_flops_per_image():
     total = stem + stage1 + stage(16, 64, 128) + stage(8, 128, 256) + \
         stage(4, 256, 512) + 2 * 512 * 10
     assert total == 1_110_845_440
-    assert flops.resnet_forward_flops(c.config["config"], 1) == total
-    assert flops.train_flops_per_unit(c.config, c.traffic) == 3 * total
+    assert resnet.forward_flops(c.config["config"], 1) == total
+    arch = reference.module_for(c.config)
+    assert arch.train_flops_per_unit(c.config, c.traffic) == 3 * total
 
 
 def test_hlo_matmul_flops_from_a_compiled_program():

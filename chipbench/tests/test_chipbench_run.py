@@ -47,7 +47,7 @@ def test_command_refuses_in_a_directory_without_the_program(tmp_path):
     env.pop("PYTHONPATH", None)
     p = subprocess.run(
         [sys.executable, "chipbench/run.py", "--workload",
-         "resnet18cifar-train-1chip", "--seed", "1", "--seconds", "1",
+         "resnet18cifar-train-b512-1chip", "--seed", "1", "--seconds", "1",
          "--trace", "0"], cwd=tmp_path, env=env, capture_output=True,
         text=True, timeout=120)
     assert p.returncode != 0 and p.stdout.strip() == ""
